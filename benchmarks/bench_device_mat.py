@@ -6,16 +6,13 @@ Two claims, both ASSERTED (not just reported):
 1. **byte identity** — the jagged-emission client + ``DeviceMaterializer``
    produce exactly the batches the host-dense path produces after
    ``jax.device_put`` (same keys, dtypes, values);
-2. **the host featurize stage shrinks toward pure I/O** — with the [B, L]
-   zero-scatter moved on-device, the client's host-side cost per batch
-   (arena slicing + concat) is strictly below the host-densify baseline, and
-   the H2D payload is strictly smaller (bytes scale with kept elements, not
-   B*L*T).
+2. **the H2D payload is strictly smaller** — bytes scale with kept
+   elements, not B*L*T.
 
-The transfer-stage time is reported but NOT asserted: the fused kernel runs
-in interpret mode on CPU here, which is orders of magnitude off real Pallas
-lowering — the roofline model (``materialization_roofline``) carries the
-device-time argument instead.
+Times (host stage, transfer stage) are reported but NOT asserted: a
+wall-clock comparison on a shared host is not a claim, and off-TPU the
+densify kernel runs in interpret mode, which is orders of magnitude off real
+Pallas lowering.
 """
 from __future__ import annotations
 
@@ -88,7 +85,7 @@ def run(quick: bool = False) -> List[BenchResult]:
         n_batches, rows, seq_len, mean_len, full_b = 48, 16, 2048, 96, 64
     feats = _synth_features(n_batches, rows, seq_len, mean_len)
 
-    # median-of-3: the host-stage gap is the headline, keep it noise-robust
+    # median-of-3 of the reported host-stage times
     host_dense_s, host_jag_s = [], []
     for _ in range(3):
         dense, td = _client_path(feats, full_b, emit_jagged=False)
@@ -125,14 +122,13 @@ def run(quick: bool = False) -> List[BenchResult]:
             assert np.array_equal(np.asarray(got[k]), np.asarray(want[k])), k
     n = len(dense)
 
-    # the two asserted claims: strictly less host featurize-stage time AND
-    # strictly fewer H2D bytes per batch than the host-densify baseline
+    # the asserted claim besides byte identity: strictly fewer H2D bytes per
+    # batch than the host-densify baseline
     assert jag_bytes < dense_bytes, (jag_bytes, dense_bytes)
-    assert t_jag < t_dense, (t_jag, t_dense)
 
     roof = materialization_roofline(
         batch=full_b, seq_len=seq_len, n_traits=3,
-        arena_rows=arena_rows // n, itemsize=4)
+        arena_rows=arena_rows // n, itemsize=4, ts_lanes=1)
     return [BenchResult(
         "device_mat/late_materialization",
         1e6 * t_jag / n,

@@ -16,6 +16,8 @@ import time
 import traceback
 from pathlib import Path
 
+from repro.launch.compile_cache import use_compile_cache
+
 MODULES = [
     "benchmarks.fig2_cost_wall",
     "benchmarks.table1_system_efficiency",
@@ -59,6 +61,7 @@ def _headline(derived: dict) -> dict:
 
 
 def main() -> None:
+    use_compile_cache()
     args = [a for a in sys.argv[1:]]
     quick = "--quick" in args
     if quick:

@@ -12,9 +12,11 @@ from repro.core.consistency import batches_equal, future_leakage_count
 from repro.core.projection import TenantProjection
 from repro.core.simulation import ProductionSim, SimConfig
 from repro.data import DatasetSpec, SimSource, open_feed
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main() -> None:
+    use_compile_cache()
     sim = ProductionSim(SimConfig(
         stream=ev.StreamConfig(n_users=4, n_items=2_000, days=5,
                                events_per_user_day_mean=50.0, seed=0),

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.core import events as ev
 from repro.core.simulation import ProductionSim, SimConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import recsys as R
 from repro.obs import Telemetry
 from repro.serve import RetrievalServer, ServeConfig
@@ -29,6 +30,7 @@ USERS = 64
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=512)
     args = ap.parse_args()

@@ -19,6 +19,7 @@ from repro.core.projection import TenantProjection
 from repro.core.simulation import ProductionSim, SimConfig
 from repro.dpp.featurize import FeatureSpec
 from repro.dpp.worker import DPPWorker
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import recsys as R
 
 SEQ_LEN = 32
@@ -38,6 +39,7 @@ def make_worker(sim):
 
 
 def main() -> None:
+    use_compile_cache()
     sim = ProductionSim(SimConfig(
         stream=ev.StreamConfig(n_users=16, n_items=2_000, days=4,
                                events_per_user_day_mean=40.0, seed=3),

@@ -24,6 +24,7 @@ from repro.core.simulation import ProductionSim, SimConfig
 from repro.data import DatasetSpec, SimSource, open_feed
 from repro.dpp.elastic import ElasticConfig, ElasticController
 from repro.dpp.featurize import FeatureSpec
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import recsys as R
 from repro.train.optimizer import AdamWConfig
 from repro.train.train_loop import Trainer, TrainerConfig
@@ -79,6 +80,7 @@ def prep(b, cfg):
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_seqrec_ckpt")

@@ -28,6 +28,7 @@ from repro.core.projection import TenantProjection
 from repro.core.simulation import ProductionSim, SimConfig
 from repro.data import DatasetSpec, StreamSource, open_feed
 from repro.dpp.featurize import FeatureSpec
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import recsys as R
 from repro.train.optimizer import AdamWConfig
 from repro.train.train_loop import Trainer, TrainerConfig
@@ -37,6 +38,7 @@ BATCH = 32
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--history-days", type=int, default=2,
                     help="warehouse days replayed by the catch-up backfill")

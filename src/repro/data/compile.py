@@ -174,6 +174,23 @@ def cell_input_sharding(cell: Any, mesh: Any):
         batch_spec, is_leaf=lambda x: isinstance(x, P))
 
 
+def _check_device_materialize(spec: DatasetSpec, depth: int,
+                              prep_fn) -> None:
+    """Refuse a ``device_materialize=True`` spec that the feed cannot
+    honour, rather than quietly densifying on the host (DESIGN §3)."""
+    why = None
+    if isinstance(spec.source, StreamSource):
+        why = "a streaming source (streaming sessions densify on the host)"
+    elif depth <= 0:
+        why = ("no device-prefetch stage (prefetch_depth is 0, or None "
+               "without a cell): nothing runs the kernel on the device")
+    elif prep_fn is not None:
+        why = "a prep_fn, which expects dense host batches"
+    if why is not None:
+        raise ValueError(f"device_materialize=True cannot be honoured with "
+                         f"{why}; set device_materialize=False")
+
+
 def open_feed(
     spec: DatasetSpec,
     sim: Any,
@@ -218,6 +235,8 @@ def open_feed(
     sharding = cell_input_sharding(cell, mesh)
     base_rows, base_batches = (
         _check_resume(spec, resume_from) if resume_from else (0, 0))
+    if spec.device_materialize:
+        _check_device_materialize(spec, depth, prep_fn)
 
     if isinstance(spec.source, StreamSource):
         from repro.streaming.backfill import ReplayFilter
@@ -280,11 +299,7 @@ def open_feed(
                     prep_fn=prep_fn, spec=spec, resume_meta=resume_meta,
                     telemetry=tel, store=sim.immutable)
 
-    # device-side late materialization: only when a device-prefetch stage
-    # exists to run the fused kernel and no prep_fn expects dense host
-    # batches — otherwise fall back to host densify (DESIGN §3 fallback
-    # rules; streaming sessions above always take the host path for now)
-    dev_mat = bool(spec.device_materialize) and depth > 0 and prep_fn is None
+    dev_mat = bool(spec.device_materialize)
     client = RebatchingClient(spec.batch_size,
                               buffer_batches=spec.buffer_batches,
                               shuffle_seed=spec.reshuffle_seed,

@@ -125,8 +125,8 @@ class DatasetSpec:
     # kernels/fused densify+decode on-accelerator instead of densifying on
     # the host. Batches are byte-identical to the host path (tested), so the
     # flag is an operational knob EXCLUDED from the resume fingerprint.
-    # Requires a device-prefetch stage and no prep_fn; open_feed silently
-    # falls back to the host path otherwise (fallback rules in DESIGN §3).
+    # Requires a batch source, a device-prefetch stage and no prep_fn;
+    # open_feed raises ValueError otherwise (DESIGN §3).
     device_materialize: bool = False
     # unified telemetry (§13): a ``repro.obs.Telemetry`` threaded by
     # ``open_feed`` through every pipeline stage (store RTT histograms, item

@@ -327,7 +327,7 @@ class RebatchingClient:
 
         In jagged-EMISSION mode the densify is skipped entirely: slots store
         per-row arena views and the full batch leaves as a compact payload
-        (see ``_pack_jagged``) for the device-side fused kernel."""
+        (see ``_pack_jagged``) for the device-side densify."""
         if self.emit_jagged:
             self._place(
                 jf.plan.b, lambda: self._jagged_emit_template(jf),

@@ -4,8 +4,8 @@
 offsets per trait — DESIGN §3 layout contract) instead of dense [B, L]
 batches. ``DeviceMaterializer`` sits inside the DevicePrefetcher's transfer
 thread: it uploads ONLY the compact arrays (the zero padding never crosses
-the PCIe/ICI link), then runs the ``kernels/fused`` densify+decode kernel on
-device and rebuilds exactly the batch dict the host-dense path would have
+the PCIe/ICI link), then runs the ``kernels/fused`` densify (Pallas) and
+timestamp decode (XLA), one jit, on device and rebuilds exactly the batch dict the host-dense path would have
 produced after ``jax.device_put`` — same keys, same order, same canonical
 dtypes, same bytes (tests/test_feed.py asserts identity in interpret mode).
 
@@ -84,7 +84,7 @@ def densify_host(batch: HostBatch) -> HostBatch:
 
 
 class DeviceMaterializer:
-    """Upload a compact jagged payload + run the fused kernel on device.
+    """Upload a compact jagged payload + densify and decode it on device.
 
     Stateless per batch except ``last_h2d_bytes`` (read by the prefetcher
     right after each call for the ``ClientStats.h2d_bytes`` counter)."""

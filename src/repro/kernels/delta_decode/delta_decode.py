@@ -23,6 +23,24 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _prefix_sum(x: jax.Array, axis: int) -> jax.Array:
+    """Inclusive int32 prefix sum of a kernel value along ``axis``.
+
+    Mosaic has no cumsum lowering; this builds the scan from ``pltpu.roll``
+    and masked adds in ceil(log2 n) steps (Hillis-Steele). Wrapping int32
+    addition is associative, so the result is bit-identical to
+    ``jnp.cumsum(x, axis, dtype=jnp.int32)``."""
+    n = x.shape[axis]
+    idx = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    k = 1
+    while k < n:
+        # roll moves element i to i + k (jnp.roll semantics); the first k
+        # positions receive wrapped-around values and must add nothing
+        x = x + jnp.where(idx >= k, pltpu.roll(x, k, axis), 0)
+        k *= 2
+    return x
+
+
 def _kernel(deltas_ref, bases_ref, out_ref, carry_ref):
     j = pl.program_id(1)
 
@@ -31,7 +49,7 @@ def _kernel(deltas_ref, bases_ref, out_ref, carry_ref):
         carry_ref[...] = jnp.zeros_like(carry_ref)
 
     block = deltas_ref[...]                          # (block_b, block_n)
-    csum = jnp.cumsum(block, axis=1, dtype=jnp.int32)
+    csum = _prefix_sum(block, axis=1)
     out_ref[...] = csum + carry_ref[...] + bases_ref[...]
     carry_ref[...] = carry_ref[...] + csum[:, -1:]
 

@@ -10,7 +10,9 @@ from repro.kernels.embedding_bag.embedding_bag import embedding_bag_kernel
 
 def embedding_bag(table: jax.Array, ids: jax.Array, mask: jax.Array,
                   combiner: str = "sum") -> jax.Array:
-    """(V, D) table, (B, L) ids/mask -> (B, D). Lane-pads D to 128.
+    """(V, D) table, (B, L) ids/mask -> (B, D). The kernel reads the table
+    as 128-lane column tiles: a reshape when D is 128, a padded copy or a
+    relayout per call otherwise.
 
     ids are clamped into [0, V) inside the kernel before the row DMA — the
     featurizer's zero-padded (and any sentinel-poisoned) lanes ride through
@@ -22,10 +24,9 @@ def embedding_bag(table: jax.Array, ids: jax.Array, mask: jax.Array,
         # valid pallas_call — the masked reduction is identically zero
         out = jnp.zeros((b, d), table.dtype)
     else:
-        dp = (128 - d % 128) % 128
-        t = jnp.pad(table, ((0, 0), (0, dp)))
         out = embedding_bag_kernel(
-            t, ids.astype(jnp.int32), mask.astype(t.dtype), bag_len=l,
+            runtime.to_lane_tiles(table), ids.astype(jnp.int32),
+            mask.astype(table.dtype), bag_len=l,
             interpret=runtime.interpret_default(),
         )[:, :d]
     if combiner == "mean":

@@ -1,22 +1,33 @@
-"""Pallas TPU kernel: fused late materialization — jagged trait arena ->
-dense right-aligned [B, L, T] block with in-window timestamp delta-decode.
+"""Late materialization on device: jagged trait arena -> dense right-aligned
+[B, L, T] block with the timestamp delta-decode, in one jit.
 
 This is the device half of the paper's §4.2 training-time reconstruction:
 the host ships only the compact values arena + offsets (no [B, L] zero
 padding over the wire), and the densify + decode run where the bandwidth
 is. All traits of a batch share one ScatterPlan, so their clipped tails
 stack as int32 columns of a single (N, T) arena (float traits ride
-bit-cast — see ops.pack_arena). TPU mapping mirrors ``kernels/jagged``:
-grid = (B,); each step DMAs the L-row window ending at ``offsets[b+1]``
-(wrapper front-pads by L so the window is always in-bounds) from HBM into
-a VMEM scratch, masks the invalid prefix, and — when the batch carries a
-delta-encoded timestamp column — rebuilds absolute timestamps with an
-in-VMEM cumsum plus the per-row (int32-wrapped) base before the (1, L, T)
-output block is written.
+bit-cast — see ops.pack_arena).
 
-The decode is the ``delta_decode`` recurrence inlined at its only training
-use site: the carry never leaves the row's VMEM window, so the int32-width
-hazard of the standalone kernel (see delta_decode/ops.py) cannot arise —
+Two stages, not one fused kernel:
+
+1. densify: the ``kernels/jagged`` Pallas kernel (Mosaic custom call
+   ``jagged_to_padded``). Grid = (B, 128-lane tiles); each step DMAs the
+   L-row window ending at ``offsets[b+1]`` (the wrapper rounds L up to
+   whole 8-row tiles and front-pads by that much, so the window is always
+   in-bounds) into VMEM, masks the invalid prefix and writes a (1, L, 128)
+   block to HBM.
+2. decode, only when the batch carries a delta-encoded timestamp column:
+   XLA ops after the kernel in the same jit re-read the (B, L) timestamp
+   lane from HBM, cumsum it in int32, add the per-row (int32-wrapped) base
+   and write the lane back. Mosaic has no cumsum lowering, and the roll-based
+   scan that it does lower (as in ``kernels/delta_decode``) keeps log2(L)
+   temporaries of the whole (L, 128) window in VMEM: compiled for a v5e at
+   B=64, that in-kernel decode fit at L=4096 but not at L=8192, where the
+   densify alone fits. The price is one extra HBM round trip of the
+   timestamp lane (``roofline.analysis.MaterializationRoofline``).
+
+The carry never leaves one row's window, so the int32-width hazard of the
+standalone delta_decode kernel (see delta_decode/ops.py) cannot arise —
 window-relative offsets are duration-bounded by codec construction.
 """
 from __future__ import annotations
@@ -25,61 +36,30 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-
-def _kernel(offsets_ref, bases_ref, values_ref, out_ref, scratch, sem, *,
-            max_len, ts_col):
-    b = pl.program_id(0)
-    end = offsets_ref[b + 1] + max_len        # +max_len: wrapper front-pad
-    start = offsets_ref[b]
-    ln = jnp.minimum(end - max_len - start, max_len)
-    copy = pltpu.make_async_copy(
-        values_ref.at[pl.ds(end - max_len, max_len), :], scratch, sem)
-    copy.start()
-    copy.wait()
-    j = jax.lax.broadcasted_iota(jnp.int32, scratch.shape, 0)
-    valid = j >= (max_len - ln)
-    win = jnp.where(valid, scratch[...], 0)
-    if ts_col >= 0:
-        # in-window delta decode: the first kept element's delta is 0 by
-        # encoding, so the cumsum over the zero-masked window yields the
-        # window-relative offset at every valid lane; adding the wrapped
-        # int32 base reproduces exactly what device_put'ing the host-dense
-        # int64 timestamps canonicalizes to (x64 is disabled)
-        col = jax.lax.broadcasted_iota(jnp.int32, scratch.shape, 1) == ts_col
-        deltas = jnp.where(col, win, 0)
-        decoded = jnp.cumsum(deltas, axis=0, dtype=jnp.int32) + bases_ref[b]
-        win = jnp.where(jnp.logical_and(col, valid), decoded, win)
-    out_ref[0] = win
+from repro.kernels.jagged.jagged import jagged_to_padded_kernel
 
 
 @functools.partial(jax.jit, static_argnames=("max_len", "ts_col", "interpret"))
-def fused_densify_kernel(
-    values_padded: jax.Array,   # (N + max_len, T) int32: front-padded arena
+def densify_decode(
+    values_tiles: jax.Array,    # (C, N + max_len, 128) int32 arena tiles
     offsets: jax.Array,         # (B+1,) int32
     ts_bases: jax.Array,        # (B,) int32 (zeros when ts_col < 0)
     max_len: int,
     ts_col: int = -1,
     interpret: bool = False,
 ) -> jax.Array:
-    b = offsets.shape[0] - 1
-    t = values_padded.shape[1]
-    kern = functools.partial(_kernel, max_len=max_len, ts_col=ts_col)
-    return pl.pallas_call(
-        kern,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),   # offsets (scalar loads)
-            pl.BlockSpec(memory_space=pltpu.SMEM),   # per-row ts bases
-            pl.BlockSpec(memory_space=pl.ANY),       # stacked arena in HBM
-        ],
-        out_specs=pl.BlockSpec((1, max_len, t), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, max_len, t), jnp.int32),
-        scratch_shapes=[
-            pltpu.VMEM((max_len, t), jnp.int32),
-            pltpu.SemaphoreType.DMA,
-        ],
-        interpret=interpret,
-    )(offsets, ts_bases, values_padded)
+    dense = jagged_to_padded_kernel(values_tiles, offsets, max_len=max_len,
+                                    interpret=interpret)
+    if ts_col < 0:
+        return dense
+    # the first kept element's delta is 0 by encoding and the kernel zeroed
+    # the invalid prefix, so the cumsum yields the window-relative offset at
+    # every valid lane; adding the wrapped int32 base reproduces exactly what
+    # device_put'ing the host-dense int64 timestamps canonicalizes to (x64
+    # is disabled)
+    lens = jnp.minimum(offsets[1:] - offsets[:-1], max_len)
+    valid = jnp.arange(max_len)[None, :] >= (max_len - lens)[:, None]
+    ts = (jnp.cumsum(dense[:, :, ts_col], axis=1, dtype=jnp.int32)
+          + ts_bases[:, None])
+    return dense.at[:, :, ts_col].set(jnp.where(valid, ts, 0))

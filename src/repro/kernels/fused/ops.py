@@ -3,8 +3,9 @@
 Layering (DESIGN §3): the host ships the **compact** jagged layout — one
 stacked int32 arena per shared ScatterPlan, offsets, and (for timestamp
 traits) window-relative int32 deltas + per-row bases. On device, ONE
-``fused_densify`` kernel launch rebuilds every trait's right-aligned
-[B, L] lanes and decodes timestamps in the same VMEM window; the dense id
+``fused_densify`` call (one jit: the ``jagged_to_padded`` Pallas kernel,
+then an XLA cumsum over the timestamp lane — see fused.py) rebuilds every
+trait's right-aligned [B, L] lanes with timestamps decoded; the dense id
 lanes then feed ``embedding_bag`` straight from HBM (no host round trip).
 
 dtype contract under jax's default x64-disabled config: the device batch is
@@ -25,7 +26,7 @@ import numpy as np
 
 from repro.kernels import runtime
 from repro.kernels.embedding_bag.ops import embedding_bag
-from repro.kernels.fused.fused import fused_densify_kernel
+from repro.kernels.fused.fused import densify_decode
 
 _I32_MAX = np.int64(2**31 - 1)
 
@@ -98,23 +99,26 @@ def fused_densify(arena: jax.Array, offsets: jax.Array, seq_len: int,
     """(N, T) int32 arena + (B+1,) offsets -> (B, L, T) int32, right-aligned,
     timestamp column (if any) delta-decoded in-window.
 
-    Front-pads the arena by L zero rows so the kernel's fixed-size DMA
-    window is always in-bounds; lane-pads T to a multiple of 128.
+    Runs the kernel on a window of L rounded up to whole 8-row tiles over
+    128-lane column tiles of the arena, front-padded by that many zero rows
+    so the kernel's fixed-size DMA window is always in-bounds. Rows are
+    pre-clipped to L (the featurizer contract), so the right-aligned last L
+    rows of the window are the answer.
     ``ts_bases`` must already be int32 (host callers wrap int64 bases with
     ``.astype(np.int32)`` — canonicalization parity, see module doc)."""
     b = offsets.shape[0] - 1
     n, t = arena.shape
     if b == 0 or seq_len == 0 or t == 0:
         return jnp.zeros((b, seq_len, t), jnp.int32)
-    tp = (128 - t % 128) % 128
-    v = jnp.pad(jnp.asarray(arena), ((seq_len, 0), (0, tp)))
+    lp = runtime.tile_rows(seq_len)
+    v = runtime.to_lane_tiles(jnp.asarray(arena), lp)
     bases = (jnp.zeros(b, jnp.int32) if ts_bases is None
              else jnp.asarray(ts_bases).astype(jnp.int32))
-    out = fused_densify_kernel(
+    out = densify_decode(
         v, jnp.asarray(offsets).astype(jnp.int32), bases,
-        max_len=seq_len, ts_col=ts_col,
+        max_len=lp, ts_col=ts_col,
         interpret=runtime.interpret_default())
-    return out[:, :, :t]
+    return out[:, lp - seq_len:, :t]
 
 
 def unpack_dense(dense: jax.Array, metas: List[Tuple[str, np.dtype]]
@@ -136,8 +140,9 @@ def late_materialize(values: Dict[str, np.ndarray], offsets: np.ndarray,
                      table: Optional[jax.Array] = None,
                      ids_trait: Optional[str] = None,
                      combiner: str = "sum") -> Dict[str, object]:
-    """One-call fused pipeline: delta-decode + densify in a single kernel
-    launch, then ``embedding_bag`` over the dense id lanes on-device.
+    """One-call device pipeline: densify + timestamp decode in one jit
+    (``fused_densify``), then ``embedding_bag`` over the dense id lanes
+    on-device.
 
     ``values`` are flat per-trait arenas (clipped tails) sharing ``offsets``;
     a ``ts_trait`` arena is given in ABSOLUTE int64 and is delta-encoded

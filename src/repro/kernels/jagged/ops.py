@@ -12,15 +12,16 @@ def jagged_to_padded(values: jax.Array, offsets: jax.Array, max_len: int
                      ) -> jax.Array:
     """values (N, D) + offsets (B+1,) -> (B, max_len, D), right-aligned.
 
-    Front-pads values by max_len zero rows so the kernel's fixed-size DMA
-    window is always in-bounds; lane-pads D to a multiple of 128."""
+    Runs the kernel on a window of whole 8-row tiles over 128-lane column
+    tiles of values, front-padded by that many zero rows so the kernel's
+    fixed-size DMA window is always in-bounds."""
     n, d = values.shape
     b = offsets.shape[0] - 1
     if b == 0 or max_len == 0:
         # zero-step grids / zero-row DMA windows are not valid pallas_calls
         return jnp.zeros((b, max_len, d), values.dtype)
-    dp = (128 - d % 128) % 128
-    v = jnp.pad(values, ((max_len, 0), (0, dp)))
-    out = jagged_to_padded_kernel(v, offsets.astype(jnp.int32), max_len,
+    lp = runtime.tile_rows(max_len)
+    out = jagged_to_padded_kernel(runtime.to_lane_tiles(values, lp),
+                                  offsets.astype(jnp.int32), lp,
                                   interpret=runtime.interpret_default())
-    return out[:, :, :d]
+    return out[:, lp - max_len:, :d]

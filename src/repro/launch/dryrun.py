@@ -23,8 +23,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_arch, list_archs
-from repro.compat import as_shardings
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_cell
 from repro.roofline.analysis import from_compiled
 from repro.roofline.hlo import parse_collectives
@@ -40,11 +39,11 @@ def run_cell(arch_id: str, shape_name: str, mesh_name: str,
     cell = build_cell(spec, shape_name, mesh, use_full=True)
 
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(
             cell.step_fn,
-            in_shardings=as_shardings(mesh, cell.in_shardings),
-            out_shardings=as_shardings(mesh, cell.out_shardings),
+            in_shardings=cell.in_shardings,
+            out_shardings=cell.out_shardings,
         )
         lowered = jitted.lower(*cell.args_spec)
         t_lower = time.time() - t0
@@ -107,10 +106,10 @@ def run_cell(arch_id: str, shape_name: str, mesh_name: str,
 
 def _measure(cell, mesh) -> dict:
     """Lower+compile a (calibration) cell and return flops/bytes/collectives."""
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(cell.step_fn,
-                         in_shardings=as_shardings(mesh, cell.in_shardings),
-                         out_shardings=as_shardings(mesh, cell.out_shardings))
+                         in_shardings=cell.in_shardings,
+                         out_shardings=cell.out_shardings)
         compiled = jitted.lower(*cell.args_spec).compile()
     ca = compiled.cost_analysis()
     if isinstance(ca, (list, tuple)):

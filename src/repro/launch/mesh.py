@@ -6,8 +6,6 @@ from typing import Tuple
 
 import jax
 
-from repro.compat import set_mesh  # noqa: F401  (re-export; see repro.compat)
-
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16x16 = 256 chips per pod; multi-pod = 2 pods = 512 chips."""

@@ -12,8 +12,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
-
 Params = Dict[str, Any]
 
 
@@ -57,7 +55,7 @@ def bag_rowsharded(
         return jax.lax.psum(jnp.sum(emb, axis=-2), model_axis)
 
     dp = tuple(data_axes) if data_axes else None
-    out = shard_map(
+    out = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(model_axis, None), P(dp, None), P(dp, None)),
         out_specs=P(dp, None),
@@ -98,7 +96,7 @@ def seq_rowsharded(table, ids, mesh, data_axes=("data",),
         return jax.lax.psum(emb, model_axis)
 
     dp = tuple(data_axes) if data_axes else None
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(model_axis, None), P(dp, None)),
         out_specs=P(dp, None, None),

@@ -131,7 +131,10 @@ def _attend_chunked(
         outs = outs[None]
     else:
         _, outs = jax.lax.scan(chunk_fn, None, (qs, qps), unroll=unroll)
-    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(b, n_chunks * qc, h, dv)
+    # one merge per reshape: under Explicit mesh axes a reshape may merge
+    # dims on only one axis group
+    out = outs.reshape(n_chunks, b, qc, h, dv).transpose(1, 0, 2, 3, 4)
+    out = out.reshape(b, n_chunks * qc, h, dv)
     return out[:, :sq]
 
 

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
-
 from repro.models.layers import _init
 
 Params = Dict[str, Any]
@@ -213,7 +211,7 @@ def moe_ffn(
         wd = ("data",) if "data" in mesh.axis_names else ()
         inner = partial(_moe_inner_2d, cfg=cfg, model_axis=model_axis,
                         data_axis=wd)
-        out = shard_map(
+        out = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(P(None, None), P(None, None),
@@ -225,7 +223,7 @@ def moe_ffn(
         P = jax.sharding.PartitionSpec
         dp = tuple(data_axes) if data_axes else None  # () -> replicated tokens
         inner = partial(_moe_inner, cfg=cfg, model_axis=model_axis)
-        out = shard_map(
+        out = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(P(dp, None), P(None, None),
@@ -233,6 +231,10 @@ def moe_ffn(
             out_specs=P(dp, None),
             check_vma=False,
         )(xt, params["router"], params["w_in"], params["w_out"])
+    if mesh is not None:
+        # shard_map hands back its out_specs layout; give the caller its
+        # input's layout so a layer scan's carry keeps one type
+        out = jax.sharding.reshard(out, jax.typeof(xt).sharding.spec)
     if "shared_w_in" in params:
         dt = x.dtype
         h = xt @ params["shared_w_in"].astype(dt)

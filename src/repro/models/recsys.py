@@ -53,6 +53,17 @@ def _seq_lookup(table, ids, cfg, dt):
     return table.astype(dt)[ids]
 
 
+def _replicated_lookup(table, ids, cfg, dt):
+    """Gather from a small replicated table; on a mesh the rows take the
+    ids' batch sharding (Explicit axes cannot infer it for a gather)."""
+    if cfg.mesh is None:
+        return table.astype(dt)[ids]
+    from jax.sharding import PartitionSpec as P
+
+    spec = jax.typeof(ids).sharding.spec
+    return table.astype(dt).at[ids].get(out_sharding=P(*spec, None))
+
+
 def _bag(table, ids, mask, combiner, cfg, dt):
     if cfg.mesh is not None:
         return bag_rowsharded(table, ids, mask, combiner, cfg.mesh,
@@ -60,18 +71,26 @@ def _bag(table, ids, mask, combiner, cfg, dt):
     return embedding_bag(table, ids, mask, combiner, dt)
 
 
-def _shard_batch_all(x, cfg):
-    """Recsys encoders have no model-parallel dims, so the ``model`` axis
-    would otherwise idle while per-chip attention/GRU activations blow up
-    16x: re-shard the batch over (data x model) for the encoder section
-    (one cheap all-to-all in, one out)."""
+def _reshard_batch(x, cfg, axes):
     if cfg.mesh is None:
         return x
     from jax.sharding import PartitionSpec as P
 
-    axes = tuple(cfg.data_axes) + ("model",)
-    return jax.lax.with_sharding_constraint(
-        x, P(axes, *([None] * (x.ndim - 1))))
+    return jax.sharding.reshard(x, P(axes, *([None] * (x.ndim - 1))))
+
+
+def _shard_batch_all(x, cfg):
+    """Recsys encoders have no model-parallel dims, so the ``model`` axis
+    would otherwise idle while per-chip attention/GRU activations blow up
+    16x: re-shard the batch over (data x model) for the encoder section
+    (one cheap all-to-all in; ``_shard_batch_data`` is the way out)."""
+    return _reshard_batch(x, cfg, tuple(cfg.data_axes) + ("model",))
+
+
+def _shard_batch_data(x, cfg):
+    """Back from the encoder section to the data-only batch layout of the
+    embedding lookups."""
+    return _reshard_batch(x, cfg, tuple(cfg.data_axes))
 
 
 def bce_with_logits(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -546,7 +565,8 @@ def dlrm_uih_forward(params, batch, cfg: DLRMUIHConfig) -> jax.Array:
                             scores_f32=(cfg.mesh is None))
     # --- UIH sequence encoder (causal, target-aware last token) ---
     e = (_seq_lookup(params["item_table"], batch["uih_item_id"], cfg, dt)
-         + params["action_table"].astype(dt)[batch["uih_action_type"]])
+         + _replicated_lookup(params["action_table"],
+                              batch["uih_action_type"], cfg, dt))
     e = _shard_batch_all(e, cfg)
     mask = _shard_batch_all(batch["uih_mask"], cfg)
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
@@ -564,18 +584,20 @@ def dlrm_uih_forward(params, batch, cfg: DLRMUIHConfig) -> jax.Array:
 
     # target-aware pooling: attention of the candidate over history (DIN-style)
     tgt = _lookup(params["item_table"], batch["cand_item_id"], cfg, dt)  # (B, D)
-    att = jnp.einsum("bsd,bd->bs", h, tgt,
+    att = jnp.einsum("bsd,bd->bs", h, _shard_batch_all(tgt, cfg),
                      preferred_element_type=jnp.float32)
     att = jax.nn.softmax(
         jnp.where(mask, att / np.sqrt(cfg.d_seq), -1e30), axis=-1
     ).astype(dt)
-    user_seq = jnp.einsum("bs,bsd->bd", att, h)                        # (B, D)
+    user_seq = _shard_batch_data(jnp.einsum("bs,bsd->bd", att, h),
+                                 cfg)                                  # (B, D)
 
     # --- DLRM-style feature interaction ---
     offsets = jnp.arange(cfg.n_sparse) * cfg.field_vocab
     sparse = _seq_lookup(params["sparse_tables"],
                          batch["sparse_ids"] + offsets, cfg, dt)
-    dense = mlp_apply(params["dense_proj"], batch["dense"].astype(dt), 1)
+    dense = _shard_batch_data(
+        mlp_apply(params["dense_proj"], batch["dense"].astype(dt), 1), cfg)
     feats = jnp.stack(
         [
             mlp_apply(params["seq_proj"], user_seq, 1),

@@ -96,17 +96,17 @@ class MaterializationRoofline:
       [B, L] arrays and ships them whole — H2D bytes scale with B*L*T
       regardless of fill;
     * **device (compact)**: only the arena + offsets cross the link
-      (bytes scale with the *kept* elements), and the ``kernels/fused`` op
+      (bytes scale with the *kept* elements), and ``kernels/fused``
       rebuilds the dense layout on-accelerator.
 
-    The fused op's HBM traffic is one arena read + one dense write. A STAGED
-    device pipeline (densify kernel -> HBM -> separate decode kernel) pays the
-    dense intermediate twice more (write + re-read), which is the quantitative
-    case for fusing decode INTO densify. Fusing the embedding lookup as well
-    buys nothing for training: the dense id lanes must reach HBM for the jit'd
-    step either way (the table is a trained param inside it), so the fusion
-    boundary stops at decode+densify — ``t_embed_extra`` is what a fused
-    embed would merely relocate, not remove.
+    The device op's HBM traffic: the densify kernel reads the arena once and
+    writes the dense block once; the timestamp decode, which runs as XLA ops
+    after the kernel (Mosaic has no cumsum), then re-reads and rewrites each
+    of the ``ts_lanes`` timestamp lanes (B*L each). Moving the embedding
+    lookup into the feed buys nothing for training: the dense id lanes must
+    reach HBM for the jit'd step either way (the table is a trained param
+    inside it), so the feed stops at densify+decode — ``t_embed_extra`` is
+    what an embed stage there would merely relocate, not remove.
     """
 
     batch: int
@@ -115,6 +115,7 @@ class MaterializationRoofline:
     arena_rows: int          # total kept elements (sum of clipped row lens)
     itemsize: int = 4        # arena lane width (int32/float32 packing)
     table_dim: int = 0       # embedding width D; 0 = no embed stage modeled
+    ts_lanes: int = 0        # delta-encoded timestamp lanes among n_traits
 
     @property
     def fill(self) -> float:
@@ -145,24 +146,16 @@ class MaterializationRoofline:
         return self.compact_h2d_bytes / H2D_BW
 
     @property
-    def fused_hbm_bytes(self) -> int:
-        """One arena read + one dense write (decode rides in VMEM for free)."""
+    def device_hbm_bytes(self) -> int:
+        """Densify: one arena read + one dense write. Decode: one read and
+        one write of each timestamp lane."""
+        lane = self.batch * self.seq_len * self.itemsize
         return (self.arena_rows * self.n_traits * self.itemsize
-                + self.dense_h2d_bytes)
+                + self.dense_h2d_bytes + 2 * self.ts_lanes * lane)
 
     @property
-    def staged_hbm_bytes(self) -> int:
-        """Separate densify and decode kernels: the dense intermediate is
-        written, re-read, and rewritten through HBM between the stages."""
-        return self.fused_hbm_bytes + 2 * self.dense_h2d_bytes
-
-    @property
-    def t_fused(self) -> float:
-        return self.fused_hbm_bytes / HBM_BW
-
-    @property
-    def t_staged(self) -> float:
-        return self.staged_hbm_bytes / HBM_BW
+    def t_device_hbm(self) -> float:
+        return self.device_hbm_bytes / HBM_BW
 
     @property
     def t_embed_extra(self) -> float:
@@ -177,7 +170,7 @@ class MaterializationRoofline:
 
     @property
     def t_device_path(self) -> float:
-        return self.t_h2d_compact + self.t_fused
+        return self.t_h2d_compact + self.t_device_hbm
 
     @property
     def t_host_path(self) -> float:
@@ -199,8 +192,8 @@ class MaterializationRoofline:
             "h2d_savings": self.h2d_savings,
             "t_h2d_dense_s": self.t_h2d_dense,
             "t_h2d_compact_s": self.t_h2d_compact,
-            "t_fused_s": self.t_fused,
-            "t_staged_s": self.t_staged,
+            "device_hbm_bytes": self.device_hbm_bytes,
+            "t_device_hbm_s": self.t_device_hbm,
             "t_embed_extra_s": self.t_embed_extra,
             "t_device_path_s": self.t_device_path,
             "t_host_path_s": self.t_host_path,
@@ -210,12 +203,14 @@ class MaterializationRoofline:
 
 def materialization_roofline(batch: int, seq_len: int, n_traits: int,
                              arena_rows: int, itemsize: int = 4,
-                             table_dim: int = 0) -> MaterializationRoofline:
+                             table_dim: int = 0, ts_lanes: int = 0
+                             ) -> MaterializationRoofline:
     """Model the host-dense vs device-compact materialization handover for
     one batch shape (see ``MaterializationRoofline``)."""
     return MaterializationRoofline(
         batch=batch, seq_len=seq_len, n_traits=n_traits,
-        arena_rows=arena_rows, itemsize=itemsize, table_dim=table_dim)
+        arena_rows=arena_rows, itemsize=itemsize, table_dim=table_dim,
+        ts_lanes=ts_lanes)
 
 
 def from_compiled(arch: str, shape: str, mesh_name: str, chips: int,

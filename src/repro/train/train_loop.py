@@ -64,7 +64,10 @@ class Trainer:
     ):
         self.loss_fn = loss_fn
         self.cfg = cfg
-        self.params = params
+        # the step donates params and optimizer state, so old and new never
+        # sit in HBM together; the trainer therefore steps its own copy and
+        # the caller's arrays stay valid
+        self.params = jax.tree.map(jax.numpy.copy, params)
         self.opt_state = adamw_init(params)
         self.ef_state = ef_init(params) if cfg.compress_grads else None
         self.step = 0
@@ -72,7 +75,7 @@ class Trainer:
         self.ckpt = (CheckpointManager(cfg.ckpt_dir, keep=cfg.keep_ckpts)
                      if cfg.ckpt_dir else None)
         self.history = []
-        self._jit_step = jax.jit(self._train_step)
+        self._jit_step = jax.jit(self._train_step, donate_argnums=(0, 1, 2))
         # set by fit(): the active Feed whose cursor rides along with model
         # checkpoints (feed_state sidecar, exactly-once resume). While a feed
         # is active, run_step defers its periodic autosave to fit — the save

@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_arch, list_archs
-from repro.launch.mesh import make_test_mesh, set_mesh
+from repro.launch.mesh import make_test_mesh
 from repro.launch.sampling import sample_args
 from repro.launch.steps import build_cell
 
@@ -29,7 +29,7 @@ def _run(arch_id: str, shape_name: str):
     mesh = make_test_mesh(1)
     cell = build_cell(spec, shape_name, mesh, use_full=False)
     args = sample_args(cell, spec.family, seed=0)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(cell.step_fn)(*args)
     return cell, out
 

@@ -360,6 +360,22 @@ def test_open_feed_device_materialize_byte_identical():
     assert 0 < dev_bytes < host_bytes
 
 
+@pytest.mark.parametrize("source,depth,prep,match", [
+    (StreamSource(), 2, None, "streaming"),
+    (SimSource(), 0, None, "device-prefetch"),
+    (SimSource(), None, None, "device-prefetch"),   # auto depth, no cell
+    (SimSource(), 2, lambda b: b, "prep_fn"),
+])
+def test_open_feed_device_materialize_refuses_host_fallback(
+        source, depth, prep, match):
+    """``device_materialize=True`` that the feed cannot honour raises before
+    anything starts; it never quietly densifies on the host."""
+    with pytest.raises(ValueError, match=match):
+        open_feed(_tiny_spec(source, prefetch_depth=depth,
+                             device_materialize=True),
+                  _sim(pin=False), prep_fn=prep)
+
+
 def test_multitenant_planner_rejects_mixed_policies():
     t1 = TenantProjection("a", 8, ("core",))
     t2 = TenantProjection("b", 8, ("core",))

@@ -22,7 +22,6 @@ SCRIPT = textwrap.dedent("""
     import dataclasses
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import set_mesh
     mesh = jax.make_mesh((2, 4), ("data", "model"))
     key = jax.random.PRNGKey(0)
 
@@ -32,7 +31,7 @@ SCRIPT = textwrap.dedent("""
     table = jax.random.normal(key, (64, 16), jnp.float32)
     ids = jax.random.randint(key, (8, 5), 0, 64)
     mask = jax.random.bernoulli(key, 0.8, (8, 5))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(lambda t, i, m: bag_rowsharded(
             t, i, m, "mean", mesh, ("data",)))(table, ids, mask)
     want = embedding_bag(table, ids, mask, "mean")
@@ -40,7 +39,7 @@ SCRIPT = textwrap.dedent("""
     # sum on some backends, leaving float32-epsilon noise near zero
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got2 = jax.jit(lambda t, i: seq_rowsharded(t, i, mesh, ("data",)))(
             table, ids)
     np.testing.assert_allclose(np.asarray(got2), np.asarray(table[ids]),
@@ -53,7 +52,7 @@ SCRIPT = textwrap.dedent("""
     p = init_moe(key, 16, cfg)
     x = jax.random.normal(key, (16, 16), jnp.float32)
     want = moe_ref(p, x, cfg)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got_fsdp = jax.jit(lambda p, x: moe_ffn(p, x, cfg, mesh=mesh))(p, x)
         cfg2d = dataclasses.replace(cfg, ep_mode="2d")
         got_2d = jax.jit(lambda p, x: moe_ffn(p, x, cfg2d, mesh=mesh))(p, x)
@@ -74,12 +73,10 @@ SCRIPT = textwrap.dedent("""
     toks = jax.random.randint(key, (4, 16), 0, 96)
     tgt = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, 96)
     base = float(loss_fn(params, toks, tgt, tc))
-    from repro.compat import as_shardings
     pspec = lm_param_specs(params, mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         f = jax.jit(lambda p, a, b: loss_fn(p, a, b, tc, mesh=mesh),
-                    in_shardings=as_shardings(
-                        mesh, (pspec, P("data", None), P("data", None))))
+                    in_shardings=(pspec, P("data", None), P("data", None)))
         dist = float(f(params, toks, tgt))
     assert abs(base - dist) < 1e-4, (base, dist)
     print("transformer OK")
@@ -101,7 +98,7 @@ SCRIPT = textwrap.dedent("""
     }
     want = R.dlrm_uih_forward(rp, batch, rc)
     rc_mesh = dataclasses.replace(rc, mesh=mesh, data_axes=("data",))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         got = jax.jit(lambda p, b: R.dlrm_uih_forward(p, b, rc_mesh))(rp, batch)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
