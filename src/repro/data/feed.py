@@ -33,6 +33,7 @@ from typing import Any, Deque, Dict, Iterator, Optional
 from repro.core.materialize import TenantShareStats
 from repro.dpp.client import ClientStats
 from repro.dpp.worker import WorkerStats
+from repro.obs.spans import stage
 from repro.streaming.session import FreshnessStats
 
 
@@ -155,13 +156,15 @@ class Feed:
         disambiguate via ``drained``). ``record=False`` suppresses the
         starvation accounting (pulls that are not the trainer's critical
         path), propagated to whichever stage owns the counters."""
-        g = getattr(self._inner, "get", None)
-        if g is not None:                       # DevicePrefetcher stage
-            out = g(timeout=timeout, record=record)
-        else:
-            out = self._inner.get_full_batch(timeout=timeout, record=record)
-            if out is not None and self._prep_fn is not None:
-                out = self._prep_fn(out)
+        with stage("feed", "get", span=None):
+            g = getattr(self._inner, "get", None)
+            if g is not None:                   # DevicePrefetcher stage
+                out = g(timeout=timeout, record=record)
+            else:
+                out = self._inner.get_full_batch(timeout=timeout,
+                                                 record=record)
+                if out is not None and self._prep_fn is not None:
+                    out = self._prep_fn(out)
         if out is not None and record and self.telemetry is not None:
             # pop the span FIFO's delivery side (record=False drains bypass
             # this on purpose — SpanTracker.drain() accounts those batches)

@@ -43,6 +43,7 @@ import time
 from typing import Callable, Deque, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from repro.core.backoff import Backoff
+from repro.obs.spans import stage
 
 
 @dataclasses.dataclass
@@ -360,15 +361,13 @@ class DPPWorkerPool:
         place stage and retires the span from the live-item map."""
         tel = self.telemetry
         if tel is None:
-            put(out)
+            with stage("dpp", "place", span=None):
+                put(out)
             return
         tel.spans.enter_item(seq, attempt=False)
-        t0 = time.perf_counter()
         try:
-            put(out)
-            sp = tel.spans.get(seq)
-            if sp is not None:
-                sp.stage("place", t0, time.perf_counter())
+            with stage("dpp", "place"):
+                put(out)
         finally:
             tel.spans.exit_item()
             tel.spans.finish_item(seq)

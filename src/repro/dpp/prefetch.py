@@ -35,6 +35,7 @@ from typing import Any, Callable, Dict, Iterator, Optional
 import numpy as np
 
 from repro.dpp.client import ClientStats
+from repro.obs.spans import stage
 
 HostBatch = Dict[str, np.ndarray]
 
@@ -157,7 +158,10 @@ class DevicePrefetcher:
             self.stats.h2d_bytes += self.materialize.last_h2d_bytes
             jax.block_until_ready(dev)
             return dev
-        prepped = self.prep_fn(host_batch) if self.prep_fn else host_batch
+        prepped = host_batch
+        if self.prep_fn:
+            with stage("prefetch", "prep", span=None):
+                prepped = self.prep_fn(host_batch)
         target = self.sharding if self.sharding is not None else self.device
         if target is not None:
             dev = jax.device_put(prepped, target)
@@ -181,15 +185,12 @@ class DevicePrefetcher:
                 tel = self._telemetry
                 bs = tel.spans.pop_emitted() if tel is not None else None
                 self._clock.enter("h2d")
-                t0 = time.perf_counter()
-                dev = self._transfer(host_batch)
-                t1 = time.perf_counter()
-                self.stats.h2d_time_s += t1 - t0
+                with stage("prefetch", "h2d", self.stats, "h2d_time_s",
+                           span=bs) as st:
+                    dev = self._transfer(host_batch)
                 if tel is not None:
-                    if bs is not None:
-                        bs.stage("h2d", t0, t1)
-                        tel.spans.push_h2d_done(bs)
-                    self._h2d_hist.observe(t1 - t0)
+                    tel.spans.push_h2d_done(bs)
+                    self._h2d_hist.observe(st.seconds)
                 if self.recycle_host:
                     rec = getattr(self.source, "recycle", None)
                     if rec is not None:

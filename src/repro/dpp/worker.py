@@ -26,7 +26,7 @@ from repro.dpp.featurize import (
     featurize,
     featurize_jagged,
 )
-from repro.obs.spans import current_span
+from repro.obs.spans import current_span, stage
 
 ProbeFn = Callable[[int], Optional[List[TrainingExample]]]  # batch idx -> examples
 
@@ -109,37 +109,30 @@ class DPPWorker:
 
     # -- single base batch -----------------------------------------------------
     def _lookup(self, examples: List[TrainingExample]) -> List[ev.EventBatch]:
-        t0 = time.perf_counter()
         # materializer-local IO accounting: the store's global stats are
         # shared across workers, so deltas of them would mix in other
         # workers' concurrent traffic
         before = self.materializer.io_stats.snapshot()
-        uihs = self.materializer.materialize_batch(examples, self.projection)
+        # decode runs on store-internal shard threads, so it folds into the
+        # scan stage; the IOStats delta keeps its weight visible
+        with stage("dpp", "scan", self.stats, "lookup_time_s"):
+            uihs = self.materializer.materialize_batch(examples,
+                                                       self.projection)
         d = self.materializer.io_stats.delta(before)
         self.stats.dedup_hits += d.dedup_hits
         self.stats.decode_cache_hits += d.decode_cache_hits
         self.stats.parallel_shards += d.parallel_shards
-        t1 = time.perf_counter()
-        self.stats.lookup_time_s += t1 - t0
         sp = current_span()
         if sp is not None:
-            # decode runs on store-internal shard threads, so it folds into
-            # the scan stage; the IOStats delta keeps its weight visible
-            sp.stage("scan", t0, t1)
             sp.meta["bytes_scanned"] = sp.meta.get("bytes_scanned", 0) + d.bytes_scanned
             sp.meta["bytes_decoded"] = sp.meta.get("bytes_decoded", 0) + d.bytes_decoded
         return uihs
 
-    def _featurize(self, examples, uihs) -> Dict[str, np.ndarray]:
-        t0 = time.perf_counter()
-        out = featurize(examples, uihs, self.feature_spec)
-        t1 = time.perf_counter()
-        self.stats.featurize_time_s += t1 - t0
+    def _featurize(self, examples, uihs, fn=featurize):
+        with stage("dpp", "featurize", self.stats, "featurize_time_s"):
+            out = fn(examples, uihs, self.feature_spec)
         self.stats.base_batches += 1
         self.stats.examples += len(examples)
-        sp = current_span()
-        if sp is not None:
-            sp.stage("featurize", t0, t1)
         return out
 
     def process(self, examples: List[TrainingExample]) -> Dict[str, np.ndarray]:
@@ -149,24 +142,14 @@ class DPPWorker:
         """Materialize + featurize into the arena+offsets form, skipping the
         [B, L] densification — ``RebatchingClient.put_jagged`` scatters the
         arena straight into the slot (one copy instead of three)."""
-        uihs = self._lookup(examples)
-        t0 = time.perf_counter()
-        out = featurize_jagged(examples, uihs, self.feature_spec)
-        t1 = time.perf_counter()
-        self.stats.featurize_time_s += t1 - t0
-        self.stats.base_batches += 1
-        self.stats.examples += len(examples)
-        sp = current_span()
-        if sp is not None:
-            sp.stage("featurize", t0, t1)
-        return out
+        return self._featurize(examples, self._lookup(examples),
+                               featurize_jagged)
 
     def _probe(self, probe: ProbeFn, idx: int) -> Optional[List[TrainingExample]]:
-        t0 = time.perf_counter()
-        out = probe(idx)
-        if self.probe_latency_s and out is not None:
-            time.sleep(self.probe_latency_s)
-        self.stats.probe_time_s += time.perf_counter() - t0
+        with stage("dpp", "probe", self.stats, "probe_time_s", span=None):
+            out = probe(idx)
+            if self.probe_latency_s and out is not None:
+                time.sleep(self.probe_latency_s)
         return out
 
     # -- serial execution (baseline for the prefetch benchmark) -----------------

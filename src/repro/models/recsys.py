@@ -146,8 +146,9 @@ def init_two_tower(key, cfg: TwoTowerConfig) -> Params:
 
 def two_tower_user(params, user_id, uih_ids, uih_mask, cfg) -> jax.Array:
     dt = cfg.compute_dtype
-    u = _lookup(params["user_table"], user_id, cfg, dt)
-    hist = _bag(params["item_table"], uih_ids, uih_mask, "mean", cfg, dt)
+    with jax.named_scope("embed"):
+        u = _lookup(params["user_table"], user_id, cfg, dt)
+        hist = _bag(params["item_table"], uih_ids, uih_mask, "mean", cfg, dt)
     z = _shard_batch_all(jnp.concatenate([u, hist], axis=-1), cfg)
     z = mlp_apply(params["user_mlp"], z, len(cfg.tower_mlp))
     return z / (jnp.linalg.norm(z.astype(jnp.float32), axis=-1, keepdims=True)
@@ -156,7 +157,9 @@ def two_tower_user(params, user_id, uih_ids, uih_mask, cfg) -> jax.Array:
 
 def two_tower_item(params, item_id, cfg) -> jax.Array:
     dt = cfg.compute_dtype
-    z = _shard_batch_all(_lookup(params["item_table"], item_id, cfg, dt), cfg)
+    with jax.named_scope("embed"):
+        z = _lookup(params["item_table"], item_id, cfg, dt)
+    z = _shard_batch_all(z, cfg)
     z = mlp_apply(params["item_mlp"], z, len(cfg.tower_mlp))
     return z / (jnp.linalg.norm(z.astype(jnp.float32), axis=-1, keepdims=True)
                 + 1e-6).astype(dt)
@@ -168,13 +171,14 @@ def two_tower_loss(params, batch, cfg: TwoTowerConfig,
     u = two_tower_user(params, batch["user_id"], batch["uih_item_id"],
                        batch["uih_mask"], cfg)
     v = two_tower_item(params, batch["cand_item_id"], cfg)
-    logits = (u @ v.T).astype(jnp.float32) / cfg.temperature   # (B, B)
-    if log_q is not None:  # correct for in-batch sampling bias
-        logits = logits - log_q[None, :]
-    labels = jnp.arange(logits.shape[0])
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
-    return jnp.mean(logz - gold)
+    with jax.named_scope("logits"):
+        logits = (u @ v.T).astype(jnp.float32) / cfg.temperature   # (B, B)
+        if log_q is not None:  # correct for in-batch sampling bias
+            logits = logits - log_q[None, :]
+        labels = jnp.arange(logits.shape[0])
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
+        return jnp.mean(logz - gold)
 
 
 def two_tower_score_candidates(params, batch, cand_ids, cfg) -> jax.Array:
@@ -229,7 +233,9 @@ def dcn_v2_forward(params, batch, cfg: DCNv2Config) -> jax.Array:
     dt = cfg.compute_dtype
     ids = batch["sparse_ids"]                                  # (B, F)
     offsets = jnp.arange(cfg.n_sparse) * cfg.field_vocab
-    emb = _seq_lookup(params["embed"], ids + offsets[None, :], cfg, dt)  # (B,F,D)
+    with jax.named_scope("embed"):
+        emb = _seq_lookup(params["embed"], ids + offsets[None, :], cfg,
+                          dt)                                  # (B,F,D)
     x0 = _shard_batch_all(jnp.concatenate(
         [emb.reshape(ids.shape[0], -1), batch["dense"].astype(dt)], axis=-1
     ), cfg)
@@ -314,15 +320,17 @@ def dien_forward(params, batch, cfg: DIENConfig) -> jax.Array:
     dt = cfg.compute_dtype
     ids, cats = batch["uih_item_id"], batch["uih_category"]
     mask = batch["uih_mask"].astype(dt)                        # (B, S)
-    e = jnp.concatenate(
-        [_seq_lookup(params["item_table"], ids, cfg, dt),
-         _seq_lookup(params["cat_table"], cats, cfg, dt)],
-        axis=-1,
-    )                                                          # (B, S, 2D)
-    tgt = jnp.concatenate(
-        [_lookup(params["item_table"], batch["cand_item_id"], cfg, dt),
-         _lookup(params["cat_table"], batch["cand_category"], cfg, dt)], axis=-1,
-    )                                                          # (B, 2D)
+    with jax.named_scope("embed"):
+        e = jnp.concatenate(
+            [_seq_lookup(params["item_table"], ids, cfg, dt),
+             _seq_lookup(params["cat_table"], cats, cfg, dt)],
+            axis=-1,
+        )                                                      # (B, S, 2D)
+        tgt = jnp.concatenate(
+            [_lookup(params["item_table"], batch["cand_item_id"], cfg, dt),
+             _lookup(params["cat_table"], batch["cand_category"], cfg, dt)],
+            axis=-1,
+        )                                                      # (B, 2D)
     e = _shard_batch_all(e, cfg)
     mask = _shard_batch_all(mask, cfg)
     tgt = _shard_batch_all(tgt, cfg)
@@ -335,8 +343,10 @@ def dien_forward(params, batch, cfg: DIENConfig) -> jax.Array:
         h = jnp.where(mk[:, None] > 0, h_new, h)
         return h, h
 
-    _, interests = jax.lax.scan(step1, h0, (e.transpose(1, 0, 2), mask.T),
-                                unroll=cfg.unroll_scans)
+    with jax.named_scope("encoder"):
+        _, interests = jax.lax.scan(step1, h0,
+                                    (e.transpose(1, 0, 2), mask.T),
+                                    unroll=cfg.unroll_scans)
     interests = interests.transpose(1, 0, 2)                   # (B, S, H)
 
     # attention of target vs interest states
@@ -423,8 +433,9 @@ def bert4rec_encode(params, ids, mask, cfg: BERT4RecConfig) -> jax.Array:
                             rope_theta=1e4, q_chunk=1 << 30,
                             unroll=cfg.unroll_scans,
                             scores_f32=(cfg.mesh is None))
-    h = _seq_lookup(params["item_table"], ids, cfg, dt) \
-        + params["pos_table"].astype(dt)[None]
+    with jax.named_scope("embed"):
+        h = _seq_lookup(params["item_table"], ids, cfg, dt) \
+            + params["pos_table"].astype(dt)[None]
     h = _shard_batch_all(h, cfg)
     mask = _shard_batch_all(mask, cfg)
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
@@ -436,8 +447,10 @@ def bert4rec_encode(params, ids, mask, cfg: BERT4RecConfig) -> jax.Array:
         hn = L.rms_norm(h, block["ln2"])
         return h + L.swiglu(block["ffn"], hn), None
 
-    h, _ = jax.lax.scan(body, h, params["blocks"], unroll=cfg.unroll_scans)
-    return L.rms_norm(h, params["final_ln"])
+    with jax.named_scope("encoder"):
+        h, _ = jax.lax.scan(body, h, params["blocks"],
+                            unroll=cfg.unroll_scans)
+        return L.rms_norm(h, params["final_ln"])
 
 
 def bert4rec_loss(params, batch, cfg: BERT4RecConfig) -> jax.Array:
@@ -451,8 +464,15 @@ def bert4rec_loss(params, batch, cfg: BERT4RecConfig) -> jax.Array:
     mask_pos = batch["mask_pos"].astype(bool)                 # (B, S) to predict
     inputs = jnp.where(mask_pos, cfg.mask_token, ids)
     h = bert4rec_encode(params, inputs, mask, cfg)            # (B, S, D)
+    with jax.named_scope("logits"):
+        return _bert4rec_nll(params, h, ids, mask_pos, batch.get("neg_ids"),
+                             cfg)
+
+
+def _bert4rec_nll(params, h, ids, mask_pos, neg_ids, cfg: BERT4RecConfig):
+    """Mean NLL of the items at masked positions: a full softmax over the
+    table, or a sampled one over shared negatives ``neg_ids``."""
     table = params["item_table"].astype(h.dtype)
-    neg_ids = batch.get("neg_ids")
     if neg_ids is None:                                       # smoke path: full softmax
         logits = jnp.einsum("bsd,vd->bsv", h, table).astype(jnp.float32)
         logz = jax.nn.logsumexp(logits, axis=-1)
@@ -564,9 +584,10 @@ def dlrm_uih_forward(params, batch, cfg: DLRMUIHConfig) -> jax.Array:
                             unroll=cfg.unroll_scans,
                             scores_f32=(cfg.mesh is None))
     # --- UIH sequence encoder (causal, target-aware last token) ---
-    e = (_seq_lookup(params["item_table"], batch["uih_item_id"], cfg, dt)
-         + _replicated_lookup(params["action_table"],
-                              batch["uih_action_type"], cfg, dt))
+    with jax.named_scope("embed"):
+        e = (_seq_lookup(params["item_table"], batch["uih_item_id"], cfg, dt)
+             + _replicated_lookup(params["action_table"],
+                                  batch["uih_action_type"], cfg, dt))
     e = _shard_batch_all(e, cfg)
     mask = _shard_batch_all(batch["uih_mask"], cfg)
     positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
@@ -579,8 +600,10 @@ def dlrm_uih_forward(params, batch, cfg: DLRMUIHConfig) -> jax.Array:
         return h + L.swiglu(block["ffn"], hn), None
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
-    h, _ = jax.lax.scan(body_fn, e, params["seq_blocks"], unroll=cfg.unroll_scans)
-    h = L.rms_norm(h, params["seq_ln"])
+    with jax.named_scope("encoder"):
+        h, _ = jax.lax.scan(body_fn, e, params["seq_blocks"],
+                            unroll=cfg.unroll_scans)
+        h = L.rms_norm(h, params["seq_ln"])
 
     # target-aware pooling: attention of the candidate over history (DIN-style)
     tgt = _lookup(params["item_table"], batch["cand_item_id"], cfg, dt)  # (B, D)
@@ -659,8 +682,10 @@ def dien_score_candidates(params, batch, cand_ids, cand_cats,
         h = jnp.where(mk[:, None] > 0, h_new, h)
         return h, h
 
-    _, interests = jax.lax.scan(step1, h0, (e.transpose(1, 0, 2), mask.T),
-                                unroll=cfg.unroll_scans)
+    with jax.named_scope("encoder"):
+        _, interests = jax.lax.scan(step1, h0,
+                                    (e.transpose(1, 0, 2), mask.T),
+                                    unroll=cfg.unroll_scans)
     interests = interests[:, 0]                                # (S, H)
 
     n = cand_ids.shape[0]

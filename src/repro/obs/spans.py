@@ -38,10 +38,18 @@ commit order); in unordered mode it is best-effort FIFO matching.
 Sampling: 1-in-``sample_every`` items get a span (seq modulo). ``sample_every=1``
 records everything (tests); the default keeps overhead well under the 2%
 budget enforced by ``benchmarks/bench_feed.py``.
+
+Profiler spans: each timed boundary runs under :class:`stage`, which also
+opens a ``jax.profiler.TraceAnnotation`` named ``repro.<layer>.<stage>``, so
+the same interval lands in the ``*Stats`` counter, the sampled span and, when
+a profiler runs, the device trace's clock (one annotation per base batch or
+step at most; ~1us each when no profiler runs).  :func:`trace_gc` adds
+``repro.host.gc`` around collections of generations 1 and 2.
 """
 from __future__ import annotations
 
 import collections
+import gc
 import json
 import threading
 import time
@@ -58,6 +66,88 @@ def current_span() -> Optional["ItemSpan"]:
     None (telemetry off / item unsampled).  Stage recorders in the worker
     and client call this; it must stay allocation-free."""
     return getattr(_TLS, "span", None)
+
+
+_TraceAnnotation = None     # jax.profiler's, imported on first use
+
+
+def _annotate(name: str):
+    """A profiler annotation ``name`` (a no-op unless a profiler runs).  The
+    data plane imports no jax until it times its first stage."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
+
+
+_CURRENT = object()
+
+
+class stage:
+    """One timed boundary, reported three ways from one pair of clock reads.
+
+        with stage("dpp", "scan", worker.stats, "lookup_time_s"):
+            ...
+
+    opens the profiler annotation ``repro.dpp.scan`` and, when the block
+    returns, adds its seconds to ``worker.stats.lookup_time_s`` and records
+    ``(t0, t1)`` as the ``scan`` stage of ``span`` (default: this thread's
+    :func:`current_span`; ``None`` records no span).  A block that raises
+    records neither: a failed attempt did not produce the data.
+    """
+
+    __slots__ = ("name", "stats", "field", "span", "t0", "t1", "_ann")
+
+    def __init__(self, layer: str, name: str, stats: Any = None,
+                 field: Optional[str] = None, span: Any = _CURRENT) -> None:
+        self.name = name
+        self.stats = stats
+        self.field = field
+        self.span = span
+        self._ann = _annotate(f"repro.{layer}.{name}")
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+    def __enter__(self) -> "stage":
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(exc_type, exc, tb)
+        if exc_type is not None:
+            return
+        if self.stats is not None:
+            setattr(self.stats, self.field,
+                    getattr(self.stats, self.field) + self.t1 - self.t0)
+        sp = current_span() if self.span is _CURRENT else self.span
+        if sp is not None:
+            sp.stage(self.name, self.t0, self.t1)
+
+
+def _gc_span(phase: str, info: Dict[str, int]) -> None:
+    if info["generation"] < 1:
+        return
+    if phase == "start":
+        _TLS.gc = _annotate("repro.host.gc")
+        _TLS.gc.__enter__()
+    else:
+        ann = getattr(_TLS, "gc", None)
+        if ann is not None:
+            _TLS.gc = None
+            ann.__exit__(None, None, None)
+
+
+def trace_gc() -> None:
+    """Annotate every collection of generations 1 and 2 as ``repro.host.gc``
+    on the thread that collects (process-wide; idempotent)."""
+    if _gc_span not in gc.callbacks:
+        _annotate("repro.host.gc")      # import jax here, never inside gc
+        gc.callbacks.append(_gc_span)
 
 
 class ItemSpan:

@@ -15,6 +15,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
+from repro.obs.spans import stage, trace_gc
 from repro.train.grad_compress import EFState, compress_with_feedback, ef_init
 from repro.train.optimizer import (
     AdamWConfig,
@@ -97,31 +98,55 @@ class Trainer:
         (gsum, lsum), _ = jax.lax.scan(
             accum, (zero, jax.numpy.zeros((), jax.numpy.float32)), microbatches)
         n = self.cfg.grad_accum
-        grads = jax.tree.map(lambda g: g / n, gsum)
-        if ef_state is not None:
-            grads, ef_state = compress_with_feedback(grads, ef_state)
-        params, opt_state, stats = adamw_update(params, grads, opt_state,
-                                                self.cfg.opt)
+        with jax.named_scope("optimizer"):
+            grads = jax.tree.map(lambda g: g / n, gsum)
+            if ef_state is not None:
+                grads, ef_state = compress_with_feedback(grads, ef_state)
+            params, opt_state, stats = adamw_update(params, grads, opt_state,
+                                                    self.cfg.opt)
         stats["loss"] = lsum / n
         return params, opt_state, ef_state, stats
 
-    def run_step(self, batch: Dict[str, np.ndarray]) -> Dict[str, float]:
-        """batch rows are split into ``grad_accum`` microbatches."""
+    def _microbatches(self, batch: Dict[str, Any]) -> Dict[str, Any]:
+        """Every batch array split into ``grad_accum`` microbatches."""
         n = self.cfg.grad_accum
         mbs = {}
         for k, v in batch.items():
             b = v.shape[0]
             assert b % n == 0, f"batch {b} not divisible by accum {n}"
             mbs[k] = v.reshape(n, b // n, *v.shape[1:])
-        self.params, self.opt_state, self.ef_state, stats = self._jit_step(
-            self.params, self.opt_state, self.ef_state, mbs)
+        return mbs
+
+    def run_step(self, batch: Dict[str, np.ndarray]) -> Dict[str, float]:
+        """batch rows are split into ``grad_accum`` microbatches."""
+        with stage("train", "dispatch", span=None):
+            with stage("train", "inputs", span=None):
+                mbs = self._microbatches(batch)
+            self.params, self.opt_state, self.ef_state, stats = \
+                self._jit_step(self.params, self.opt_state, self.ef_state,
+                               mbs)
         self.step += 1
-        out = {k: float(v) for k, v in stats.items()}
+        with stage("train", "readback", span=None):
+            out = {k: float(v) for k, v in stats.items()}
         self.history.append(out)
         if (self.ckpt and self.step % self.cfg.ckpt_every == 0
                 and self._fit_feed is None):
             self.save()
         return out
+
+    def step_hlo_text(self, batch: Dict[str, Any]) -> str:
+        """The compiled train step's HLO text for batches shaped like
+        ``batch``: each instruction's ``metadata={op_name=...}`` names the
+        model scope (``embed``, ``encoder``, ``logits``, ``optimizer``) that
+        a device op in a profiler trace belongs to."""
+        def spec(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                        sharding=getattr(x, "sharding", None))
+
+        mbs = jax.eval_shape(self._microbatches, batch)
+        args = jax.tree.map(spec, (self.params, self.opt_state,
+                                   self.ef_state))
+        return self._jit_step.lower(*args, mbs).compile().as_text()
 
     # -- checkpointing ----------------------------------------------------------
     def save(self) -> None:
@@ -133,8 +158,9 @@ class Trainer:
         feed = self._fit_feed
         if feed is not None and getattr(feed, "can_checkpoint", False):
             feed_state = feed.checkpoint()
-        self.ckpt.save(self.step, state, extra={"step": self.step},
-                       feed_state=feed_state)
+        with stage("train", "checkpoint", span=None):
+            self.ckpt.save(self.step, state, extra={"step": self.step},
+                           feed_state=feed_state)
         tel = self._telemetry()
         if tel is not None:
             tel.events.emit("checkpoint_save", step=self.step,
@@ -183,7 +209,9 @@ class Trainer:
         tel = self._telemetry()
         step_hist = (tel.registry.histogram(
             "repro_train_step_seconds",
-            help="device train-step wall time") if tel is not None else None)
+            help="host time of run_step: dispatch and loss read-back")
+            if tel is not None else None)
+        trace_gc()
         t0 = time.perf_counter()
 
         def batches():
@@ -232,24 +260,28 @@ class Trainer:
 
         try:
             for batch in batches():
-                ts = time.perf_counter()
-                stats = self.run_step(batch)
-                dt_step = time.perf_counter() - ts
-                if record is not None:
-                    record(dt_step)
-                if step_hist is not None:
-                    step_hist.observe(dt_step)
-                if (self.ckpt and self._fit_feed is not None
-                        and self.step % self.cfg.ckpt_every == 0):
-                    # deferred from run_step: the feed's trained-row counter
-                    # advanced in record() above, so the feed_state sidecar
-                    # now names exactly this step's training frontier
-                    self.save()
-                if self.step % self.cfg.log_every == 0:
-                    dt = time.perf_counter() - t0
-                    print(f"step {self.step:5d} loss={stats['loss']:.4f} "
-                          f"gnorm={stats['grad_norm']:.3f} ({dt:.1f}s)",
-                          flush=True)
+                with jax.profiler.StepTraceAnnotation("repro.train.step",
+                                                      step_num=self.step):
+                    ts = time.perf_counter()
+                    stats = self.run_step(batch)
+                    dt_step = time.perf_counter() - ts
+                    if record is not None:
+                        record(dt_step)
+                    if step_hist is not None:
+                        step_hist.observe(dt_step)
+                    if (self.ckpt and self._fit_feed is not None
+                            and self.step % self.cfg.ckpt_every == 0):
+                        # deferred from run_step: the feed's trained-row
+                        # counter advanced in record() above, so the
+                        # feed_state sidecar now names exactly this step's
+                        # training frontier
+                        self.save()
+                    if self.step % self.cfg.log_every == 0:
+                        dt = time.perf_counter() - t0
+                        print(f"step {self.step:5d} "
+                              f"loss={stats['loss']:.4f} "
+                              f"gnorm={stats['grad_norm']:.3f} ({dt:.1f}s)",
+                              flush=True)
                 if max_steps and self.step >= max_steps:
                     break
                 if (self.cfg.max_wall_s is not None

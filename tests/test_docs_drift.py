@@ -78,3 +78,28 @@ def test_every_emitted_event_kind_documented_in_design():
     missing = sorted(k for k in kinds if f"`{k}`" not in DESIGN)
     assert not missing, (
         f"DESIGN.md §13 event-kind list is missing: {missing}")
+
+
+# a profiler span: stage("<layer>", "<stage>" -> repro.<layer>.<stage>, or an
+# annotation named by its literal; a model scope: jax.named_scope("<scope>")
+_STAGE_RE = re.compile(r"\bstage\(\s*\"([a-z_]+)\",\s*\"([a-z_]+)\"")
+_SPAN_RE = re.compile(
+    r"(?:_annotate|Annotation)\(\s*\"(repro\.[a-z_]+\.[a-z_]+)\"")
+_SCOPE_RE = re.compile(r"named_scope\(\s*\"([a-z_]+)\"\s*\)")
+
+
+def test_every_span_and_scope_documented_in_design():
+    section = DESIGN[DESIGN.index("## §13"):DESIGN.index("## §14")]
+    spans, scopes = set(), set()
+    for path in SRC.rglob("*.py"):
+        text = path.read_text()
+        spans.update(f"repro.{a}.{b}" for a, b in _STAGE_RE.findall(text))
+        spans.update(_SPAN_RE.findall(text))
+        scopes.update(_SCOPE_RE.findall(text))
+    # the walk itself works
+    assert {"repro.dpp.scan", "repro.train.step", "repro.host.gc"} <= spans
+    assert {"embed", "encoder", "logits", "optimizer"} <= scopes
+    missing = sorted(n for n in spans | scopes if f"| `{n}` |" not in section)
+    assert not missing, (
+        f"DESIGN.md §13 has no row for these profiler spans or model "
+        f"scopes: {missing}")
