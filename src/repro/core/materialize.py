@@ -38,10 +38,14 @@ group's scan bounds, and issues ONE ``multi_range_scan`` covering every
 example × feature group. The store's planner dedupes the canonicalized
 duplicates (surfaced as ``IOStats.dedup_hits``), executes shard groups in
 parallel, and decodes each stripe at most once; the materializer then
-reassembles per-example UIHs from the shared windows. A true-LRU window cache
-(hits promoted) persists windows ACROSS batches, the DPP-worker analogue of
-the store-side block cache — all of a user's same-day requests share one
-immutable window, so streaming and user-bucketed batch jobs both hit heavily.
+reassembles per-example UIHs from the shared windows, one ``np.concatenate``
+per column for all of a window's examples. Each window's requests are built
+and sent once; the member examples beyond the first are handed to the store
+as ``twins``, which ``dedup_hits`` counts like in-plan duplicates. A true-LRU
+window cache (hits promoted) persists windows ACROSS batches, the DPP-worker
+analogue of the store-side block cache — all of a user's same-day requests
+share one immutable window, so streaming and user-bucketed batch jobs both
+hit heavily.
 """
 from __future__ import annotations
 
@@ -179,13 +183,9 @@ class Materializer:
             return self._project_fat(example, projection)
 
         assert example.version is not None, "VLM example missing version metadata"
-        mutable_part = example.mutable_uih or ev.empty_batch(self.schema)
         immutable_part = self._fetch_immutable(example, projection)
-        out = self._concat_and_project(immutable_part, mutable_part, projection)
-        self.stats.examples += 1
-        self.stats.immutable_events += ev.batch_len(immutable_part)
-        self.stats.mutable_events += ev.batch_len(mutable_part)
-        return out
+        return self._join_window(immutable_part, [example],
+                                 *self._output_shape(projection))[0]
 
     def materialize_batch(
         self,
@@ -205,24 +205,22 @@ class Materializer:
         out: List[Optional[ev.EventBatch]] = [None] * len(examples)
         # 1) group VLM examples by window key (batch-local dedupe scope)
         members: "OrderedDict[tuple, List[int]]" = OrderedDict()
+        fp = _projection_fingerprint(projection)
         for i, ex in enumerate(examples):
             if ex.is_fat or ex.version is None:
                 out[i] = self.materialize(ex, projection)
                 continue
-            members.setdefault(self._window_key(ex, projection), []).append(i)
+            members.setdefault(self._window_key(ex, fp), []).append(i)
 
         windows, _, _ = self._resolve_windows(members, examples, projection)
 
         # reassemble per-example UIHs from the shared windows
+        shape = self._output_shape(projection)
         for key, idxs in members.items():
-            imm = windows[key]
-            for i in idxs:
-                ex = examples[i]
-                mutable_part = ex.mutable_uih or ev.empty_batch(self.schema)
-                out[i] = self._concat_and_project(imm, mutable_part, projection)
-                self.stats.examples += 1
-                self.stats.immutable_events += ev.batch_len(imm)
-                self.stats.mutable_events += ev.batch_len(mutable_part)
+            uihs = self._join_window(windows[key], [examples[i] for i in idxs],
+                                     *shape)
+            for i, uih in zip(idxs, uihs):
+                out[i] = uih
         return out  # type: ignore[return-value]
 
     def materialize_multi(
@@ -261,12 +259,13 @@ class Materializer:
         out: Dict[str, List[Optional[ev.EventBatch]]] = {
             p.name: [None] * len(examples) for p in projections}
         members: "OrderedDict[tuple, List[int]]" = OrderedDict()
+        fp = _projection_fingerprint(union)
         for i, ex in enumerate(examples):
             if ex.is_fat or ex.version is None:
                 for p in projections:
                     out[p.name][i] = self.materialize(ex, p)
                 continue
-            members.setdefault(self._window_key(ex, union), []).append(i)
+            members.setdefault(self._window_key(ex, fp), []).append(i)
 
         # hold the scan-time lease through the share estimates so the
         # generation the accounting is pinned to cannot be GC'd (and thus
@@ -280,25 +279,18 @@ class Materializer:
             if lease is not None:
                 lease.release()
 
+        shapes = [self._output_shape(p) for p in projections]
         for key, idxs in members.items():
             imm = windows[key]
             # carve once per (window, tenant), shared across member examples;
             # a tenant that IS the union (N=1) uses the window as fetched —
             # it was scanned under exactly that projection, the carve is a
             # no-op re-slice/re-project
-            views = {p.name: (imm if p is union
-                              else project_view(imm, p, self.schema))
-                     for p in projections}
-            for i in idxs:
-                ex = examples[i]
-                mutable_part = ex.mutable_uih or ev.empty_batch(self.schema)
-                for p in projections:
-                    view = views[p.name]
-                    out[p.name][i] = self._concat_and_project(
-                        view, mutable_part, p)
-                    self.stats.examples += 1
-                    self.stats.immutable_events += ev.batch_len(view)
-                    self.stats.mutable_events += ev.batch_len(mutable_part)
+            group = [examples[i] for i in idxs]
+            for p, shape in zip(projections, shapes):
+                view = imm if p is union else project_view(imm, p, self.schema)
+                for i, uih in zip(idxs, self._join_window(view, group, *shape)):
+                    out[p.name][i] = uih
         return out  # type: ignore[return-value]
 
     def _resolve_windows(
@@ -337,26 +329,28 @@ class Materializer:
 
         gens: dict = {key: self._window_generation(rep)
                       for key, rep, _ in to_fetch}
+        scan_shape = self._scan_shape(projection)
 
         def collect():
             reqs: List[ScanRequest] = []
             spans: List[Tuple[tuple, TrainingExample, int, int, int]] = []
+            twins = 0
             for key, rep, n_members in to_fetch:
                 gen = gens[key]
-                canonical = self._requests_for(rep, projection, gen)
+                canonical = self._requests_for(rep, scan_shape, gen)
                 lo = len(reqs)
-                # one canonicalized request tuple PER member example: the plan
-                # covers example × group, the store dedupes (IOStats.dedup_hits)
-                for _ in range(n_members):
-                    reqs.extend(canonical)
+                # one request set per window; its other member examples are
+                # twins the store counts as dedup_hits
+                reqs.extend(canonical)
+                twins += (n_members - 1) * len(canonical)
                 spans.append((key, rep, lo, lo + len(canonical), gen))
-            return reqs, spans
+            return reqs, spans, twins
 
         fetched: List[Tuple[tuple, TrainingExample, int]] = []
         lease = None
         if to_fetch:
             while True:
-                reqs, fetch_spans = collect()
+                reqs, fetch_spans, twins = collect()
                 # share accounting (hold_lease) takes a transient lease that
                 # names — and retains — the generation live when the scan
                 # STARTS: reading store.generation after the scan would name
@@ -370,7 +364,8 @@ class Materializer:
                 if hold_lease:
                     lease = self.immutable.acquire_lease()
                 try:
-                    parts = self.immutable.multi_range_scan(reqs, self.io_stats)
+                    parts = self.immutable.multi_range_scan(
+                        reqs, self.io_stats, twins)
                     break
                 except GenerationUnavailable:
                     if lease is not None:
@@ -420,15 +415,17 @@ class Materializer:
         the union co-scan reads, via the store's metadata-exact estimator."""
         store = self.immutable
         share_stats.co_scans += 1
+        union_shape = self._scan_shape(union)
+        solo_shapes = [self._scan_shape(p) for p in projections]
         for key, rep, gen in fetched:
             try:
                 union_b = sum(
                     store.estimate_scan(r)[1]
-                    for r in self._requests_for(rep, union, gen))
+                    for r in self._requests_for(rep, union_shape, gen))
                 solo = [
                     sum(store.estimate_scan(r)[1]
-                        for r in self._requests_for(rep, p, gen))
-                    for p in projections
+                        for r in self._requests_for(rep, shape, gen))
+                    for shape in solo_shapes
                 ]
             except GenerationUnavailable:
                 continue  # the generation flipped after the fetch; skip
@@ -439,15 +436,14 @@ class Materializer:
             share_stats.union_overfetch_bytes += max(0, union_b - max(solo))
 
     # -- helpers ---------------------------------------------------------------
-    def _window_key(
-        self, example: TrainingExample, projection: Optional[TenantProjection]
-    ) -> tuple:
+    def _window_key(self, example: TrainingExample, fingerprint) -> tuple:
         """Pins the *content* of an immutable window: same watermark + same
         length + same checksum => identical event set regardless of the
-        per-request lookback start_ts."""
+        per-request lookback start_ts. ``fingerprint``: the projection's
+        ``_projection_fingerprint``, computed once per batch."""
         v = example.version
         return (example.user_id, v.end_ts, v.seq_len, v.checksum, v.generation,
-                _projection_fingerprint(projection))
+                fingerprint)
 
     def _window_cache_get(self, key: tuple) -> Optional[ev.EventBatch]:
         if not self.window_cache_size:
@@ -480,13 +476,24 @@ class Materializer:
         self.stats.pin_misses += 1
         return -1
 
+    def _scan_shape(self, projection: Optional[TenantProjection]):
+        """What a projection asks of every window, resolved once per batch:
+        ``(max_events, [(group, traits)])`` (-1 / None = the example's
+        logged length / the group's traits)."""
+        if projection is None:
+            return -1, [(g, None) for g in self.schema.feature_groups]
+        return projection.seq_len, [
+            (g, projection.traits_for(self.schema, g))
+            for g in projection.feature_groups]
+
     def _requests_for(
         self,
         example: TrainingExample,
-        projection: Optional[TenantProjection],
+        scan_shape,
         generation: int = -1,
     ) -> List[ScanRequest]:
-        """One ScanRequest per feature group for the example's window.
+        """One ScanRequest per feature group for the example's window
+        (``scan_shape`` from ``_scan_shape``).
 
         Sequence-length projection: the tenant wants the *most recent*
         ``projection.seq_len`` events of the full UIH. The immutable fetch uses
@@ -495,12 +502,7 @@ class Materializer:
         the final concat+trim keeps exactly seq_len events."""
         meta = example.version
         assert meta is not None
-        groups = (
-            projection.feature_groups
-            if projection is not None
-            else tuple(self.schema.feature_groups)
-        )
-        max_events = -1 if projection is None else projection.seq_len
+        max_events, groups = scan_shape
         return [
             ScanRequest(
                 user_id=example.user_id,
@@ -508,25 +510,26 @@ class Materializer:
                 start_ts=meta.start_ts,
                 end_ts=meta.end_ts,
                 max_events=meta.seq_len if max_events < 0 else max_events,
-                traits=None if projection is None else projection.traits_for(self.schema, g),
+                traits=traits,
                 generation=generation,
             )
-            for g in groups
+            for g, traits in groups
         ]
 
     def _fetch_immutable(
         self, example: TrainingExample, projection: Optional[TenantProjection]
     ) -> ev.EventBatch:
         gen = self._window_generation(example)
+        scan_shape = self._scan_shape(projection)
         try:
             parts = self.immutable.multi_range_scan(
-                self._requests_for(example, projection, gen), self.io_stats)
+                self._requests_for(example, scan_shape, gen), self.io_stats)
         except GenerationUnavailable:
             # pinned generation GC'd between check and scan: remediate live
             self.stats.pin_misses += 1
             gen = -1
             parts = self.immutable.multi_range_scan(
-                self._requests_for(example, projection, gen), self.io_stats)
+                self._requests_for(example, scan_shape, gen), self.io_stats)
         imm = self._join_groups(parts)
         self._maybe_check(example, imm, projection, gen)
         self.stats.windows_fetched += 1
@@ -604,32 +607,60 @@ class Materializer:
                        "reproduce the logged window" if stale else "")
                 )
 
-    def _concat_and_project(
+    def _output_shape(self, projection: Optional[TenantProjection]):
+        """``(traits, seq_len)`` of a projection's output, resolved once per
+        batch: (None, -1) keeps every column and event."""
+        if projection is None:
+            return None, -1
+        return projection.all_traits(self.schema), projection.seq_len
+
+    def _join_window(
         self,
         immutable_part: ev.EventBatch,
-        mutable_part: ev.EventBatch,
-        projection: Optional[TenantProjection],
-    ) -> ev.EventBatch:
-        if projection is not None:
-            traits = projection.all_traits(self.schema)
-            mutable_part = ev.project_traits(mutable_part, [t for t in traits if t in mutable_part])
-            if immutable_part:
-                immutable_part = ev.project_traits(
-                    immutable_part, [t for t in traits if t in immutable_part]
-                )
-        full = ev.concat_batches([immutable_part, mutable_part])
-        if not full:
-            cols = (
-                projection.all_traits(self.schema)
-                if projection is not None
-                else self.schema.trait_names
-            )
-            return ev.empty_batch(self.schema, cols)
-        if projection is not None:
-            n = ev.batch_len(full)
-            if n > projection.seq_len:
-                full = ev.slice_batch(full, n - projection.seq_len, n)
-        return full
+        members: Sequence[TrainingExample],
+        traits: Optional[Sequence[str]],
+        seq_len: int,
+    ) -> List[ev.EventBatch]:
+        """The UIHs of a window's member examples: the immutable window, then
+        each example's mutable slice, cut to the most recent ``seq_len``
+        events (-1 = all) and to ``traits`` (None = the first non-empty
+        part's columns). One ``np.concatenate`` per output column serves
+        every member; each example's columns are disjoint slices of it."""
+        muts = [ex.mutable_uih for ex in members]
+        n_muts = [ev.batch_len(m) if m else 0 for m in muts]
+        n_imm = ev.batch_len(immutable_part)
+        st = self.stats
+        st.examples += len(members)
+        st.immutable_events += n_imm * len(members)
+        st.mutable_events += sum(n_muts)
+        if not n_imm:  # no window: each example is its mutable slice alone
+            return [self._tail(m, n, traits, seq_len)
+                    for m, n in zip(muts, n_muts)]
+        parts, bounds, start = [], [], 0
+        for m, n_mut in zip(muts, n_muts):
+            parts.append(immutable_part)
+            if n_mut:
+                parts.append(m)
+            n = n_imm + n_mut
+            bounds.append((start + (n - seq_len if 0 <= seq_len < n else 0),
+                           start + n))
+            start += n
+        cols = [(t, np.concatenate([p[t] for p in parts]))
+                for t in self._keys(immutable_part, traits)]
+        return [{t: c[lo:hi] for t, c in cols} for lo, hi in bounds]
+
+    def _tail(self, batch: Optional[ev.EventBatch], n: int,
+              traits: Optional[Sequence[str]], seq_len: int) -> ev.EventBatch:
+        """A copy of the most recent ``seq_len`` of a batch's ``n`` events."""
+        if not n:
+            return ev.empty_batch(self.schema, traits)
+        lo = n - seq_len if 0 <= seq_len < n else 0
+        return {t: batch[t][lo:].copy() for t in self._keys(batch, traits)}
+
+    @staticmethod
+    def _keys(batch: ev.EventBatch, traits: Optional[Sequence[str]]):
+        return batch.keys() if traits is None else [t for t in traits
+                                                   if t in batch]
 
     def _project_fat(
         self, example: TrainingExample, projection: Optional[TenantProjection]

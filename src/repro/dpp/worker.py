@@ -124,8 +124,11 @@ class DPPWorker:
         self.stats.parallel_shards += d.parallel_shards
         sp = current_span()
         if sp is not None:
-            sp.meta["bytes_scanned"] = sp.meta.get("bytes_scanned", 0) + d.bytes_scanned
-            sp.meta["bytes_decoded"] = sp.meta.get("bytes_decoded", 0) + d.bytes_decoded
+            for key, n in (("bytes_scanned", d.bytes_scanned),
+                           ("bytes_decoded", d.bytes_decoded),
+                           ("windows", d.windows_assembled),
+                           ("stripes", d.stripes_read)):
+                sp.meta[key] = sp.meta.get(key, 0) + n
         return uihs
 
     def _featurize(self, examples, uihs, fn=featurize):

@@ -15,8 +15,13 @@ columns entirely at the byte level. An optional zstd pass compresses the column
 payloads (off by default: the bit-level codecs already dominate, and benchmarks
 measure both).
 
+``StripeLayout`` is a stripe's header parsed once — where compaction encodes
+the stripe (``encode_stripe_and_layout``) or when a stored stripe is first
+loaded — so the store's read path decodes columns from the kept offsets and
+codec parameters and never parses msgpack per read.
+
 ``StripeDecodeCache`` is the store-side block-cache analogue (§4.2.3) for the
-batched read path: a bounded, thread-safe LRU of *decoded* stripes keyed on
+batched read path: a bounded, thread-safe LRU of *decoded* columns keyed on
 ``(blob identity, traits)``, so a hot stripe touched by many requests of one
 batch (same-user, same-day traffic) is decoded once and shared.
 """
@@ -37,6 +42,9 @@ MAGIC = b"UIHC"
 VERSION = 1
 
 _WIDTHS = (np.uint8, np.uint16, np.uint32, np.uint64)
+# dtype instances, not types: numpy converts a type on every call
+_WIDTH_OF_SIZE = {np.dtype(w).itemsize: np.dtype(w) for w in _WIDTHS}
+_INT64 = np.dtype(np.int64)
 
 
 def _pack_unsigned(arr: np.ndarray) -> Tuple[bytes, dict]:
@@ -56,11 +64,17 @@ def _pack_unsigned(arr: np.ndarray) -> Tuple[bytes, dict]:
 
 
 def _unpack_unsigned(payload: bytes, meta: dict, dtype: np.dtype) -> np.ndarray:
-    if meta["codec"] == "empty":
+    return _unpack(payload, meta["codec"], meta.get("w"), meta.get("lo"), dtype)
+
+
+def _unpack(payload: bytes, codec: str, w: Optional[int], lo: Optional[int],
+            dtype: np.dtype) -> np.ndarray:
+    if codec == "empty":
         return np.zeros(0, dtype=dtype)
-    w = {1: np.uint8, 2: np.uint16, 4: np.uint32, 8: np.uint64}[meta["w"]]
-    arr = np.frombuffer(payload, dtype=w).astype(np.int64) + meta["lo"]
-    return arr.astype(dtype)
+    arr = np.frombuffer(payload, dtype=_WIDTH_OF_SIZE[w]).astype(_INT64)
+    if lo:
+        arr += lo
+    return arr.astype(dtype, copy=False)
 
 
 def encode_column(arr: np.ndarray, encoding: str) -> Tuple[bytes, dict]:
@@ -106,12 +120,12 @@ def decode_column(payload: bytes, meta: dict, dtype: np.dtype) -> np.ndarray:
     if codec == "empty":
         return np.zeros(0, dtype=dtype)
     if codec == "delta":
-        inner = dict(meta)
-        inner["codec"] = meta["inner"]
-        deltas = _unpack_unsigned(payload, inner, np.int64)
-        out = np.cumsum(deltas) + meta["base"]
-        # cumsum includes deltas[0]=0 so out[0]=base
-        return out.astype(dtype)
+        out = _unpack(payload, meta["inner"], meta.get("w"), meta.get("lo"),
+                      _INT64)
+        # deltas[0] is 0: seeded with the base, the running sum is the column
+        out[0] += meta["base"]
+        np.add.accumulate(out, out=out)  # np.cumsum, minus its dispatch cost
+        return out.astype(dtype, copy=False)
     if codec == "bitmap":
         n = meta["n"]
         bits = np.unpackbits(np.frombuffer(payload, dtype=np.uint8), count=n)
@@ -141,12 +155,13 @@ def stripe_checksum(batch: ev.EventBatch) -> int:
     return crc & 0xFFFFFFFF
 
 
-def encode_stripe(
+def encode_stripe_and_layout(
     batch: ev.EventBatch,
     schema: ev.TraitSchema,
     compress: bool = False,
-) -> bytes:
-    """Encode an event batch into a self-describing stripe blob."""
+) -> Tuple[bytes, "StripeLayout"]:
+    """Encode an event batch into a self-describing stripe blob, and keep
+    the header it wrote as the blob's ``StripeLayout``."""
     n = ev.batch_len(batch)
     cols: List[dict] = []
     payloads: List[bytes] = []
@@ -166,12 +181,20 @@ def encode_stripe(
         import zstandard as zstd
 
         body = zstd.ZstdCompressor(level=3).compress(body)
-    header = msgpack.packb(
-        {"n": n, "cols": cols, "zstd": bool(compress),
-         "crc": stripe_checksum(batch)},
-        use_bin_type=True,
-    )
-    return MAGIC + struct.pack("<HI", VERSION, len(header)) + header + body
+    header = {"n": n, "cols": cols, "zstd": bool(compress),
+              "crc": stripe_checksum(batch)}
+    packed = msgpack.packb(header, use_bin_type=True)
+    blob = MAGIC + struct.pack("<HI", VERSION, len(packed)) + packed + body
+    return blob, StripeLayout(header, 10 + len(packed))
+
+
+def encode_stripe(
+    batch: ev.EventBatch,
+    schema: ev.TraitSchema,
+    compress: bool = False,
+) -> bytes:
+    """Encode an event batch into a self-describing stripe blob."""
+    return encode_stripe_and_layout(batch, schema, compress)[0]
 
 
 def _read_header(blob: bytes) -> Tuple[dict, int]:
@@ -187,47 +210,82 @@ def stripe_num_events(blob: bytes) -> int:
     return header["n"]
 
 
+class StripeLayout:
+    """A stripe's header, parsed once: ``header`` is the dict ``_read_header``
+    returns and ``body_off`` where the column payloads start; ``cols`` maps
+    each column to its header entry, dtype and payload byte range, so a read
+    decodes a column straight from the blob with no header parse."""
+
+    __slots__ = ("header", "body_off", "cols")
+
+    def __init__(self, header: dict, body_off: int):
+        self.header = header
+        self.body_off = body_off
+        # a zstd body is inflated before the read: offsets count from it
+        base = 0 if header["zstd"] else body_off
+        self.cols: Dict[str, Tuple[dict, np.dtype, int, int]] = {
+            m["name"]: (m, np.dtype(m["dtype"]), base + m["off"],
+                        base + m["off"] + m["len"])
+            for m in header["cols"]}
+
+    @classmethod
+    def parse(cls, blob: bytes) -> "StripeLayout":
+        return cls(*_read_header(blob))
+
+    def decoded_bytes(self, traits: Optional[Sequence[str]] = None) -> int:
+        """Payload bytes a decode of ``traits`` (None = every column) reads."""
+        if traits is None:
+            return sum(m["len"] for m in self.header["cols"])
+        cols = self.cols
+        return sum(cols[t][0]["len"] for t in traits if t in cols)
+
+    def decode(self, blob: bytes, traits: Sequence[str]) -> Tuple[np.ndarray, ...]:
+        """The ``traits`` columns, in that order; only their bytes are read."""
+        data = memoryview(blob)
+        if self.header["zstd"]:
+            import zstandard as zstd
+
+            data = zstd.ZstdDecompressor().decompress(data[self.body_off:])
+        try:
+            specs = [self.cols[t] for t in traits]
+        except KeyError as e:
+            raise AssertionError(f"stripe missing trait {e}") from None
+        return tuple(decode_column(data[lo:hi], meta, dtype)
+                     for meta, dtype, lo, hi in specs)
+
+
 def decode_stripe(
     blob: bytes,
     schema: ev.TraitSchema,
     traits: Optional[Sequence[str]] = None,
 ) -> ev.EventBatch:
-    """Decode a stripe; ``traits`` enables byte-level selective decoding."""
-    header, body_off = _read_header(blob)
-    body = blob[body_off:]
-    if header["zstd"]:
-        import zstandard as zstd
-
-        body = zstd.ZstdDecompressor().decompress(body)
-    want = set(traits) if traits is not None else None
-    out: ev.EventBatch = {}
-    for meta in header["cols"]:
-        name = meta["name"]
-        if want is not None and name not in want:
-            continue  # selective decode: skip at byte level
-        payload = body[meta["off"] : meta["off"] + meta["len"]]
-        out[name] = decode_column(payload, meta, np.dtype(meta["dtype"]))
-    if want is not None:
-        missing = want - set(out)
+    """Decode a stripe's columns into a batch, in the stripe's column order;
+    ``traits`` enables byte-level selective decoding."""
+    layout = StripeLayout.parse(blob)
+    names = [m["name"] for m in layout.header["cols"]]
+    if traits is not None:
+        want = set(traits)
+        missing = want - set(names)
         assert not missing, f"stripe missing traits {missing}"
-    return out
+        names = [t for t in names if t in want]
+    return dict(zip(names, layout.decode(blob, names)))
 
 
 class StripeDecodeCache:
-    """Bounded LRU of decoded stripes keyed on ``(blob identity, traits)``.
+    """Bounded LRU of decoded columns keyed on ``(blob identity, traits)``.
 
     The cache holds a reference to each cached blob, so ``id(blob)`` stays
     unique among live keys (an evicted entry drops its reference and the key
-    with it). Hits return a shallow copy of the column dict — the arrays are
-    shared read-only, the dict is caller-private. Thread-safe: the batched
-    executor decodes from several shard threads concurrently.
+    with it). Entries are tuples of read-only arrays, shared by every caller.
+    Thread-safe: the batched executor decodes from several shard threads
+    concurrently.
     """
 
     def __init__(self, max_entries: int = 256):
         assert max_entries > 0
         self.max_entries = max_entries
-        # key -> (blob ref, decoded batch)
-        self._entries: "OrderedDict[Tuple[int, Optional[Tuple[str, ...]]], Tuple[bytes, ev.EventBatch]]" = OrderedDict()
+        # key -> (blob ref, decoded columns)
+        self._entries: "OrderedDict[Tuple[int, Tuple[str, ...]], Tuple[bytes, Tuple[np.ndarray, ...]]]" = OrderedDict()
         self._lock = threading.Lock()
         self.hits = 0
         self.misses = 0
@@ -235,27 +293,28 @@ class StripeDecodeCache:
     def get(
         self,
         blob: bytes,
-        schema: ev.TraitSchema,
-        traits: Optional[Sequence[str]] = None,
-    ) -> Tuple[ev.EventBatch, bool]:
-        """Decoded stripe + whether it was served from cache."""
-        key = (id(blob), tuple(traits) if traits is not None else None)
+        layout: StripeLayout,
+        traits: Tuple[str, ...],
+    ) -> Tuple[Tuple[np.ndarray, ...], bool]:
+        """The ``traits`` columns of the stripe ``blob`` (laid out as
+        ``layout``) + whether they were served from cache."""
+        key = (id(blob), traits)
         with self._lock:
             entry = self._entries.get(key)
             if entry is not None and entry[0] is blob:
                 self._entries.move_to_end(key)
                 self.hits += 1
-                return dict(entry[1]), True
-        batch = decode_stripe(blob, schema, traits)
-        for arr in batch.values():  # shared across callers: freeze, don't corrupt
+                return entry[1], True
+        cols = layout.decode(blob, traits)
+        for arr in cols:  # shared across callers: freeze, don't corrupt
             arr.flags.writeable = False
         with self._lock:
             self.misses += 1
-            self._entries[key] = (blob, batch)
+            self._entries[key] = (blob, cols)
             self._entries.move_to_end(key)
             while len(self._entries) > self.max_entries:
                 self._entries.popitem(last=False)
-        return dict(batch), False
+        return cols, False
 
     def clear(self) -> None:
         with self._lock:
@@ -263,18 +322,3 @@ class StripeDecodeCache:
 
     def __len__(self) -> int:
         return len(self._entries)
-
-
-def decoded_bytes_for(blob: bytes, traits: Optional[Sequence[str]] = None) -> int:
-    """How many payload bytes a (possibly projected) decode touches.
-
-    Used by the benchmarks to account selective-decoding I/O savings without
-    relying on wall-clock noise.
-    """
-    header, _ = _read_header(blob)
-    want = set(traits) if traits is not None else None
-    total = 0
-    for meta in header["cols"]:
-        if want is None or meta["name"] in want:
-            total += meta["len"]
-    return total

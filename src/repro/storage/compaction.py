@@ -86,13 +86,15 @@ class CompactionPipeline:
         for lo in range(0, n, self.cfg.stripe_len):
             hi = min(lo + self.cfg.stripe_len, n)
             piece = ev.slice_batch(cols, lo, hi)
-            blob = columnar.encode_stripe(piece, self.schema, self.cfg.compress)
+            blob, layout = columnar.encode_stripe_and_layout(
+                piece, self.schema, self.cfg.compress)
             out.append(
                 Stripe(
                     start_ts=int(piece["timestamp"][0]),
                     end_ts=int(piece["timestamp"][-1]),
                     n_events=hi - lo,
                     blob=blob,
+                    layout=layout,
                 )
             )
         return out

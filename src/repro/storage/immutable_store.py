@@ -28,6 +28,13 @@ savings: ``dedup_hits`` (requests answered by an identical in-batch twin),
 ``decode_cache_hits`` (stripe decodes skipped), and ``parallel_shards``
 (cumulative shard fanout executed concurrently by batched scans).
 
+A scan assembles its window **column by column**: each ``Stripe`` carries
+its header parsed once (``columnar.StripeLayout``), the requested traits of
+the chosen stripes are decoded from the kept offsets, each output column is
+ONE ``np.concatenate`` over those stripes, and the window is trimmed by one
+``searchsorted`` pair on the timestamp column plus the tail cut of the
+sequence-length budget (``IOStats.windows_assembled``).
+
 **Generation leases** (bifurcated O2O protocol, §3.2): streaming training has
 examples in flight that reference the generation observed at T_request; daily
 compaction must not yank that generation out from under them. A publisher
@@ -45,6 +52,7 @@ revalidation.
 from __future__ import annotations
 
 import bisect
+import copy
 import dataclasses
 import threading
 import time
@@ -64,6 +72,15 @@ class Stripe:
     end_ts: int
     n_events: int
     blob: bytes
+    # the blob's header, parsed once: compaction hands over the layout it
+    # encoded, any other maker gets it parsed here
+    layout: Optional[columnar.StripeLayout] = dataclasses.field(
+        default=None, compare=False, repr=False)
+
+    def __post_init__(self):
+        if self.layout is None:
+            object.__setattr__(self, "layout",
+                               columnar.StripeLayout.parse(self.blob))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -164,17 +181,40 @@ class IOStats:
     degraded_scans: int = 0     # reads that failed on EVERY replica (retryable)
     partial_reissues: int = 0   # failed node groups re-issued while completed
     #                             sibling groups of the same plan were retained
+    windows_assembled: int = 0  # scans whose stripes were assembled column by
+    #                             column into a window (one per (user, group))
 
     def snapshot(self) -> "IOStats":
-        return dataclasses.replace(self)
+        return copy.copy(self)
 
     def delta(self, since: "IOStats") -> "IOStats":
-        return IOStats(*(getattr(self, f.name) - getattr(since, f.name)
-                         for f in dataclasses.fields(IOStats)))
+        out = copy.copy(self)
+        out._add(since, -1)
+        return out
 
     def merge(self, other: "IOStats") -> None:
-        for f in dataclasses.fields(IOStats):
-            setattr(self, f.name, getattr(self, f.name) + getattr(other, f.name))
+        self._add(other, 1)
+
+    def _add(self, other: "IOStats", sign: int) -> None:
+        # plain adds, field by field: this runs several times per base batch
+        self.seeks += sign * other.seeks
+        self.stripes_read += sign * other.stripes_read
+        self.bytes_scanned += sign * other.bytes_scanned
+        self.bytes_decoded += sign * other.bytes_decoded
+        self.requests += sign * other.requests
+        self.batched_requests += sign * other.batched_requests
+        self.dedup_hits += sign * other.dedup_hits
+        self.decode_cache_hits += sign * other.decode_cache_hits
+        self.parallel_shards += sign * other.parallel_shards
+        self.pinned_scans += sign * other.pinned_scans
+        self.subsumed_hits += sign * other.subsumed_hits
+        self.failovers += sign * other.failovers
+        self.hedged_reads += sign * other.hedged_reads
+        self.hedge_wins += sign * other.hedge_wins
+        self.breaker_opens += sign * other.breaker_opens
+        self.degraded_scans += sign * other.degraded_scans
+        self.partial_reissues += sign * other.partial_reissues
+        self.windows_assembled += sign * other.windows_assembled
 
 
 @dataclasses.dataclass
@@ -191,16 +231,21 @@ class ScanPlan:
 
     The grouping key is the executor's concurrency domain: the monolith keys
     by shard, the disaggregated ``ShardedUIHStore`` keys by store node.
+
+    ``twins`` counts identical requests the caller folded away before
+    planning (the materializer sends each window's requests once, not once
+    per member example); they count as dedup hits like in-plan twins.
     """
 
     unique: List[ScanRequest]          # deduped requests, first-seen order
     assignment: List[int]              # original request idx -> unique idx
     shard_groups: Dict[int, List[int]]  # shard/node -> indices into ``unique``
     derived: Dict[int, int] = dataclasses.field(default_factory=dict)
+    twins: int = 0
 
     @property
     def dedup_hits(self) -> int:
-        return len(self.assignment) - len(self.unique)
+        return len(self.assignment) - len(self.unique) + self.twins
 
     @property
     def subsumed(self) -> int:
@@ -211,7 +256,7 @@ class ScanPlan:
         return len(self.shard_groups)
 
 
-def build_scan_plan(reqs, route, effective_traits) -> ScanPlan:
+def build_scan_plan(reqs, route, effective_traits, twins: int = 0) -> ScanPlan:
     """Shared planner behind every store implementation's ``plan()``:
     dedupe identical requests, subsume projection-contained ones
     (union-projection planning), group the surviving roots by ``route(req)``
@@ -219,7 +264,8 @@ def build_scan_plan(reqs, route, effective_traits) -> ScanPlan:
     the sharded client).
 
     ``effective_traits(req)`` resolves a request's trait set (None = the
-    group's full schema) so subsumption compares real column sets."""
+    group's full schema) so subsumption compares real column sets;
+    ``twins`` is passed through to ``ScanPlan.twins``."""
     index: Dict[ScanRequest, int] = {}
     unique: List[ScanRequest] = []
     assignment: List[int] = []
@@ -264,7 +310,7 @@ def build_scan_plan(reqs, route, effective_traits) -> ScanPlan:
             continue
         shard_groups.setdefault(route(r), []).append(j)
     return ScanPlan(unique=unique, assignment=assignment,
-                    shard_groups=shard_groups, derived=derived)
+                    shard_groups=shard_groups, derived=derived, twins=twins)
 
 
 class ImmutableUIHStore:
@@ -444,16 +490,19 @@ class ImmutableUIHStore:
         shard = self.router.route(user_id)
         return shard, self._table_for(generation)[shard].get((user_id, group))
 
-    def _decode(self, s: Stripe, traits, stats: IOStats) -> ev.EventBatch:
+    def _decode(self, s: Stripe, traits: Tuple[str, ...],
+                stats: IOStats) -> Tuple[np.ndarray, ...]:
+        """The ``traits`` columns of one stripe, decoded from its kept
+        layout (or served by the decode LRU)."""
         if self.decode_cache is None:
-            stats.bytes_decoded += columnar.decoded_bytes_for(s.blob, traits)
-            return columnar.decode_stripe(s.blob, self.schema, traits)
-        batch, hit = self.decode_cache.get(s.blob, self.schema, traits)
+            stats.bytes_decoded += s.layout.decoded_bytes(traits)
+            return s.layout.decode(s.blob, traits)
+        cols, hit = self.decode_cache.get(s.blob, s.layout, traits)
         if hit:
             stats.decode_cache_hits += 1
         else:
-            stats.bytes_decoded += columnar.decoded_bytes_for(s.blob, traits)
-        return batch
+            stats.bytes_decoded += s.layout.decoded_bytes(traits)
+        return cols
 
     def _select_stripes(self, req: ScanRequest, entry) -> List[Stripe]:
         """The stripe run a request reads: overlap [start_ts, end_ts], walked
@@ -501,25 +550,34 @@ class ImmutableUIHStore:
         traits = req.traits or self.schema.group_traits(req.group)
         if req.generation >= 0 and req.generation != self.generation:
             stats.pinned_scans += 1
-        shard, entry = self._locate(req.user_id, req.group, req.generation)
+        _, entry = self._locate(req.user_id, req.group, req.generation)
         if entry is None:
             return ev.empty_batch(self.schema, traits)
         stats.seeks += 1  # single-level layout: one seek per (user,group) run
         chosen = self._select_stripes(req, entry)
         if not chosen:
             return ev.empty_batch(self.schema, traits)
+        return self._assemble(req, tuple(traits), chosen, stats)
 
-        parts: List[ev.EventBatch] = []
-        for s in chosen:
-            stats.stripes_read += 1
-            stats.bytes_scanned += len(s.blob)
-            parts.append(self._decode(s, traits, stats))
-        out = ev.concat_batches(parts)
-        if not out:
-            return ev.empty_batch(self.schema, traits)
-        out = ev.time_slice(out, req.start_ts, req.end_ts)
-        # keep the most recent max_events (tenant sequence-length budget)
-        return ev.tail_view(out, req.max_events)
+    def _assemble(self, req: ScanRequest, traits: Tuple[str, ...],
+                  chosen: List[Stripe], stats: IOStats) -> ev.EventBatch:
+        """The column-wise pass: decode the requested traits of the chosen
+        stripes, build each column with one ``np.concatenate``, keep the
+        events inside ``[start_ts, end_ts]`` (one ``searchsorted`` pair on
+        the timestamp column) and of those the most recent ``max_events``."""
+        stats.windows_assembled += 1
+        stats.stripes_read += len(chosen)
+        stats.bytes_scanned += sum(len(s.blob) for s in chosen)
+        # the temporal predicate needs timestamps even when not projected
+        need = traits if "timestamp" in traits else traits + ("timestamp",)
+        cols = [np.concatenate(c) for c in
+                zip(*[self._decode(s, need, stats) for s in chosen])]
+        ts = cols[need.index("timestamp")]
+        lo = int(ts.searchsorted(req.start_ts, side="left"))
+        hi = int(ts.searchsorted(req.end_ts, side="right"))
+        if 0 <= req.max_events < hi - lo:
+            lo = hi - req.max_events
+        return {t: c[lo:hi] for t, c in zip(traits, cols)}
 
     def scan(self, req: ScanRequest) -> ev.EventBatch:
         """Bounded range scan with 3-dimensional projection pushdown."""
@@ -529,9 +587,10 @@ class ImmutableUIHStore:
     def _effective_traits(self, req: ScanRequest) -> Tuple[str, ...]:
         return req.traits or self.schema.group_traits(req.group)
 
-    def plan(self, reqs: Sequence[ScanRequest]) -> ScanPlan:
+    def plan(self, reqs: Sequence[ScanRequest], twins: int = 0) -> ScanPlan:
         """Dedupe identical requests, subsume projection-contained ones, and
-        group the surviving root requests by shard.
+        group the surviving root requests by shard (``twins``: identical
+        requests the caller already folded away, see ``ScanPlan``).
 
         Subsumption (union-projection planning): among requests sharing
         (user, group, bounds, generation), one whose traits are a subset and
@@ -541,7 +600,7 @@ class ImmutableUIHStore:
         projections over the same window cost ONE storage scan."""
         return build_scan_plan(
             reqs, lambda r: self.router.route(r.user_id),
-            self._effective_traits)
+            self._effective_traits, twins)
 
     def _carve(self, req: ScanRequest, wide: ev.EventBatch) -> ev.EventBatch:
         """Serve a subsumed request from its covering request's result:
@@ -614,11 +673,12 @@ class ImmutableUIHStore:
         self,
         reqs: Sequence[ScanRequest],
         out_stats: Optional[IOStats] = None,
+        twins: int = 0,
     ) -> List[ev.EventBatch]:
         """Batched scan (paper: 'optimized multi-range scan with parallel I/O'):
         plans (dedupe + shard grouping), then executes shards concurrently —
         see ``plan()`` / ``execute_plan()``."""
-        return self.execute_plan(self.plan(reqs), out_stats)
+        return self.execute_plan(self.plan(reqs, twins), out_stats)
 
     # -- introspection ---------------------------------------------------------
     def live_placement(self):

@@ -10,7 +10,8 @@ interchangeable. The contract is behavioral, not just structural:
     planned (dedupe + union-projection subsumption) and executed with the
     implementation's parallelism (shard threads / node fanout); results come
     back in original request order and the call's ``IOStats`` delta lands in
-    the caller's ``out_stats``.
+    the caller's ``out_stats``. ``twins`` counts identical requests the
+    caller folded away before the call; they count as ``dedup_hits``.
   * ``acquire_lease`` — pins ONE consistent generation for the holder: on the
     sharded store this is an epoch barrier (every node pins the same
     generation; a bulk load can never interleave with lease acquisition).
@@ -101,7 +102,7 @@ class StoreProtocol(Protocol):
     # -- read path -----------------------------------------------------------
     def scan(self, req: ScanRequest) -> ev.EventBatch: ...
 
-    def plan(self, reqs: Sequence[ScanRequest]) -> ScanPlan: ...
+    def plan(self, reqs: Sequence[ScanRequest], twins: int = 0) -> ScanPlan: ...
 
     def execute_plan(
         self, plan: ScanPlan, out_stats: Optional[IOStats] = None
@@ -111,6 +112,7 @@ class StoreProtocol(Protocol):
         self,
         reqs: Sequence[ScanRequest],
         out_stats: Optional[IOStats] = None,
+        twins: int = 0,
     ) -> List[ev.EventBatch]: ...
 
     def estimate_scan(self, req: ScanRequest) -> Tuple[int, int]: ...

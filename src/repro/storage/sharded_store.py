@@ -714,7 +714,7 @@ class ShardedUIHStore:
         plane, not data I/O."""
         return self._node_for(req.user_id, req.generation).estimate_scan(req)
 
-    def plan(self, reqs: Sequence[ScanRequest]) -> ScanPlan:
+    def plan(self, reqs: Sequence[ScanRequest], twins: int = 0) -> ScanPlan:
         """Client-side planning: dedupe + union-projection subsumption over
         the whole batch (a request answered by an in-plan twin or carved from
         a wider root never crosses the network at all), roots grouped by
@@ -722,7 +722,7 @@ class ShardedUIHStore:
         return build_scan_plan(
             reqs,
             lambda r: self._node_of(r.user_id, r.generation),
-            self._effective_traits)
+            self._effective_traits, twins)
 
     def execute_plan(
         self, plan: ScanPlan, out_stats: Optional[IOStats] = None
@@ -798,8 +798,9 @@ class ShardedUIHStore:
         self,
         reqs: Sequence[ScanRequest],
         out_stats: Optional[IOStats] = None,
+        twins: int = 0,
     ) -> List[ev.EventBatch]:
-        return self.execute_plan(self.plan(reqs), out_stats)
+        return self.execute_plan(self.plan(reqs, twins), out_stats)
 
     # -- stats + introspection -------------------------------------------------
     @property

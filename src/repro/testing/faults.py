@@ -268,9 +268,9 @@ class FaultyStore(_Delegate):
         self._maybe_fault()
         return self.inner.scan(req)
 
-    def multi_range_scan(self, reqs, out_stats=None):
+    def multi_range_scan(self, reqs, out_stats=None, twins=0):
         self._maybe_fault()
-        return self.inner.multi_range_scan(reqs, out_stats)
+        return self.inner.multi_range_scan(reqs, out_stats, twins)
 
     def execute_plan(self, plan, out_stats=None):
         self._maybe_fault()

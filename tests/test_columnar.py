@@ -61,8 +61,9 @@ def test_selective_decode_only_requested():
 def test_selective_decode_touches_fewer_bytes():
     batch = _random_batch(1024)
     blob = columnar.encode_stripe(batch, SCHEMA)
-    full = columnar.decoded_bytes_for(blob)
-    partial = columnar.decoded_bytes_for(blob, ("timestamp", "item_id"))
+    layout = columnar.StripeLayout.parse(blob)
+    full = layout.decoded_bytes()
+    partial = layout.decoded_bytes(("timestamp", "item_id"))
     assert 0 < partial < full
 
 

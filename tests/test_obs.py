@@ -369,7 +369,9 @@ def test_dataset_spec_telemetry_excluded_from_identity():
 CHAOS_FAULTS = [
     FaultSpec("worker_crash", 1),               # pool self-healing + restart
     FaultSpec("compaction_during_scan", 2),     # generation flip races a read
-    FaultSpec("node_flap", 3, node=1, duration=2),  # replica failover + breaker
+    # replica failover + breaker: two worker threads race for the scan ticks,
+    # so the flap spans four of them to be sure a read of node 1 lands in it
+    FaultSpec("node_flap", 3, node=1, duration=4),
 ]
 
 
@@ -378,7 +380,7 @@ def chaos_run(tmp_path_factory):
     """One chaotic 4-node r=2 run, every item sampled, shared by the
     completeness and acceptance-report tests."""
     sim = make_sim(users=6, days=2, seed=5, nodes=4, replication=2)
-    # a single failure must flip the breaker: the flap lasts 2 scan ticks, so
+    # a single failure must flip the breaker: the flap lasts 4 scan ticks, so
     # the default threshold of 3 consecutive failures may never be reached
     for b in sim.immutable._breakers:
         b.threshold = 1

@@ -82,7 +82,7 @@ def test_decode_cache_hits_on_overlapping_windows(sim):
 
 def test_decode_cache_lru_bound_and_identity():
     cache = columnar.StripeDecodeCache(max_entries=2)
-    blobs = []
+    stripes = []
     for seed in range(3):
         rng = np.random.default_rng(seed)
         n = 32
@@ -90,22 +90,22 @@ def test_decode_cache_lru_bound_and_identity():
             "timestamp": np.sort(rng.integers(0, 10**9, n)).astype(np.int64),
             "item_id": rng.integers(0, 1000, n).astype(np.int64),
         }
-        blobs.append(columnar.encode_stripe(batch, SCHEMA))
+        stripes.append(columnar.encode_stripe_and_layout(batch, SCHEMA))
     traits = ("timestamp", "item_id")
-    a, hit = cache.get(blobs[0], SCHEMA, traits)
+    a, hit = cache.get(*stripes[0], traits)
     assert not hit
-    _, hit = cache.get(blobs[0], SCHEMA, traits)
+    _, hit = cache.get(*stripes[0], traits)
     assert hit
-    cache.get(blobs[1], SCHEMA, traits)
-    cache.get(blobs[0], SCHEMA, traits)   # promote 0 over 1
-    cache.get(blobs[2], SCHEMA, traits)   # evicts 1 (LRU), not 0
-    _, hit = cache.get(blobs[0], SCHEMA, traits)
+    cache.get(*stripes[1], traits)
+    cache.get(*stripes[0], traits)   # promote 0 over 1
+    cache.get(*stripes[2], traits)   # evicts 1 (LRU), not 0
+    _, hit = cache.get(*stripes[0], traits)
     assert hit
-    _, hit = cache.get(blobs[1], SCHEMA, traits)
+    _, hit = cache.get(*stripes[1], traits)
     assert not hit
     # cached arrays are frozen: in-place mutation must fail loudly
     with pytest.raises(ValueError):
-        a["item_id"][0] = -1
+        a[1][0] = -1
 
 
 def test_latency_model_charged_per_shard(sim):
@@ -221,3 +221,105 @@ def test_mixed_fat_and_vlm_batch(sim):
     assert batches_equal(outs[0], mat.materialize(sim.examples[0], PROJ))
     assert batches_equal(outs[1], mat.materialize(fat_ex, PROJ))
     assert batches_equal(outs[2], mat.materialize(sim.examples[1], PROJ))
+
+
+# -- column-wise scan: the store's counters ------------------------------------
+
+# recorded on this fixed batch with the per-stripe scan path, before the
+# column-wise pass replaced it: the counters must not move
+_FIXED_BATCH_COUNTERS = {
+    "projected": dict(requests=7, seeks=7, stripes_read=38,
+                      bytes_scanned=14139, bytes_decoded=3169, dedup_hits=25,
+                      decode_cache_hits=0),
+    "full": dict(requests=21, seeks=21, stripes_read=114,
+                 bytes_scanned=52466, bytes_decoded=11754, dedup_hits=75,
+                 decode_cache_hits=0),
+}
+
+
+@pytest.mark.parametrize("which", ["projected", "full"])
+def test_scan_counters_unchanged_on_a_fixed_batch(sim, which):
+    projection = PROJ if which == "projected" else None
+    batch = sim.examples[-24:] + sim.examples[-24:-16]   # repeated examples
+    sim.immutable.decode_cache.clear()
+    first = sim.materializer(validate_checksum=False)
+    first.materialize_batch(batch, projection)
+    d = first.io_stats
+    want = _FIXED_BATCH_COUNTERS[which]
+    assert {k: getattr(d, k) for k in want} == want
+    # every window read stripes, so every scan assembled one window
+    assert d.windows_assembled == d.requests
+    # the same batch again: every stripe from the decode LRU, nothing decoded
+    again = sim.materializer(validate_checksum=False)
+    again.materialize_batch(batch, projection)
+    d2 = again.io_stats
+    assert d2.decode_cache_hits == d2.stripes_read == want["stripes_read"]
+    assert d2.bytes_decoded == 0
+    assert d2.dedup_hits == want["dedup_hits"]
+
+
+def test_iostats_arithmetic_covers_every_field():
+    import dataclasses
+
+    from repro.storage.immutable_store import IOStats
+
+    names = [f.name for f in dataclasses.fields(IOStats)]
+    a = IOStats(*range(1, len(names) + 1))
+    b = IOStats(*(10 * i for i in range(1, len(names) + 1)))
+    snap = a.snapshot()
+    a.merge(b)
+    assert [getattr(a, n) for n in names] == [11 * i for i in range(1, len(names) + 1)]
+    assert [getattr(a.delta(snap), n) for n in names] == \
+        [10 * i for i in range(1, len(names) + 1)]
+    assert [getattr(snap, n) for n in names] == list(range(1, len(names) + 1))
+
+
+def test_scan_span_meta_counts_windows_and_stripes(sim):
+    from repro.dpp.featurize import FeatureSpec
+    from repro.dpp.worker import DPPWorker
+    from repro.obs.spans import SpanTracker, current_span
+
+    spec = FeatureSpec(seq_len=64, uih_traits=("item_id", "timestamp"))
+    worker = DPPWorker(sim.materializer(validate_checksum=False), PROJ, spec,
+                       sim.schema)
+    batch = sim.examples[-12:] + sim.examples[-12:-8]
+    tr = SpanTracker(sample_every=1)
+    tr.mint(0)
+    tr.enter_item(0)
+    try:
+        worker.process_jagged(batch)
+        meta = dict(current_span().meta)
+    finally:
+        tr.exit_item()
+    d = worker.materializer.io_stats
+    windows = {(e.user_id, e.version.end_ts, e.version.seq_len,
+                e.version.checksum) for e in batch}
+    assert meta["windows"] == d.windows_assembled == len(windows) > 0
+    assert meta["stripes"] == d.stripes_read > meta["windows"]
+    assert meta["bytes_scanned"] == d.bytes_scanned > 0
+
+
+def test_materialize_batch_equals_per_example_with_mutable_and_audit(sim):
+    """Repeated users, non-empty mutable slices, checksums validated: the
+    window-wide join gives each example what ``materialize`` gives it."""
+    late = [e for e in sim.examples[-32:] if e.version is not None]
+    with_mutable = [e for e in late
+                    if e.mutable_uih and ev.batch_len(e.mutable_uih) > 0]
+    assert with_mutable and len({e.user_id for e in late}) < len(late)
+    batch = late + with_mutable[:4] + late[:3]
+    for projection in (PROJ, None, *table1_tenants(256, 64, 8).values()):
+        solo = sim.materializer(validate_checksum=True)
+        planned = sim.materializer(validate_checksum=True)
+        want = [solo.materialize(e, projection) for e in batch]
+        got = planned.materialize_batch(batch, projection)
+        for a, b in zip(got, want):
+            assert list(a) == list(b)
+            for k in b:
+                assert a[k].dtype == b[k].dtype
+                np.testing.assert_array_equal(a[k], b[k])
+        if projection is None:   # full windows: every one checksum-validated
+            assert planned.stats.checksum_validated == \
+                planned.stats.windows_fetched > 0
+        assert planned.stats.checksum_failures == 0
+        for f in ("examples", "immutable_events", "mutable_events"):
+            assert getattr(planned.stats, f) == getattr(solo.stats, f)

@@ -267,3 +267,142 @@ def test_symmetric_sharding_zero_fanout_for_bucketed_batch():
     store = ImmutableUIHStore(SCHEMA, n_shards=n_shards)
     reqs = [ScanRequest(u, "core", 0, 10**12) for u in users]
     assert store.fanout(reqs) == 1
+
+
+# -- column-wise window assembly: byte identity with the per-stripe reference --
+
+def _reference_scan(node, req):
+    """The window as the per-stripe path built it: each chosen stripe decoded
+    from its raw blob with its header parsed, then ``concat_batches``,
+    ``time_slice`` and ``tail_view``."""
+    traits = req.traits or node.schema.group_traits(req.group)
+    _, entry = node._locate(req.user_id, req.group, req.generation)
+    chosen = node._select_stripes(req, entry) if entry is not None else []
+    parts = [columnar.decode_stripe(s.blob, node.schema, traits) for s in chosen]
+    out = ev.concat_batches(parts)
+    if not out:
+        return ev.empty_batch(node.schema, traits)
+    return ev.tail_view(ev.time_slice(out, req.start_ts, req.end_ts),
+                        req.max_events)
+
+
+def _assert_same_columns(got, want):
+    assert list(got) == list(want)
+    for k in want:
+        assert got[k].dtype == want[k].dtype, k
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+
+
+@pytest.fixture(scope="module")
+def windows_stores():
+    """A monolith and a 2-node sharded store over the same two generations:
+    generation 0 is leased, then generation 1 scrubs an item from it, so a
+    pinned scan of generation 0 reads a retained table."""
+    from repro.storage.sharded_store import ShardedUIHStore
+
+    gen = _gen(users=5, days=6)
+    as_of = 5 * ev.MS_PER_DAY
+    truth = gen.history_until(0, as_of)
+    scrub = make_scrub(deleted_items=[int(truth["item_id"][5])])
+    source = lambda uid, lo, hi: ev.time_slice(gen.history_until(uid, hi), lo, hi)
+    pipe = CompactionPipeline(SCHEMA, CompactionConfig(stripe_len=16))
+    stores = {"monolith": ImmutableUIHStore(SCHEMA, n_shards=4),
+              "sharded": ShardedUIHStore(SCHEMA, n_shards=4, n_nodes=2)}
+    leases = []
+    for store in stores.values():
+        pipe.run(source, list(range(5)), as_of, store, generation=0)
+        leases.append(store.acquire_lease())
+        pipe.run(source, list(range(5)), as_of, store, scrub=scrub,
+                 generation=1)
+    yield stores, truth, as_of
+    for lease in leases:
+        lease.release()
+    for store in stores.values():
+        store.close()
+
+
+def _window_cases(truth, as_of):
+    ts = truth["timestamp"]
+    mid_lo, mid_hi = int(ts[20]) + 1, int(ts[len(ts) - 20])  # inside stripes
+    return {
+        "all_traits": ScanRequest(0, "core", 0, as_of),
+        "trait_subset": ScanRequest(0, "core", 0, as_of,
+                                    traits=("timestamp", "item_id")),
+        "other_group_subset": ScanRequest(0, "engagement", 0, as_of,
+                                          traits=("timestamp", "like")),
+        "below_window": ScanRequest(0, "core", 0, as_of, max_events=5),
+        "above_window": ScanRequest(0, "core", 0, as_of, max_events=10**6),
+        "cut_inside_stripes": ScanRequest(0, "sideinfo", mid_lo, mid_hi),
+        "bounds_on_events": ScanRequest(0, "core", int(ts[20]),
+                                        int(ts[len(ts) - 20])),
+        "cut_and_budget": ScanRequest(0, "core", mid_lo, mid_hi,
+                                      max_events=7,
+                                      traits=("timestamp", "action_type")),
+        "empty_window": ScanRequest(0, "core", mid_hi, mid_lo),
+        "before_history": ScanRequest(0, "core", -10, -1),
+        "missing_user": ScanRequest(99, "core", 0, as_of),
+        "pinned_retained": ScanRequest(0, "core", 0, as_of, generation=0),
+        "pinned_budget": ScanRequest(0, "core", mid_lo, as_of, max_events=40,
+                                     generation=0),
+    }
+
+
+_CASES = ("all_traits", "trait_subset", "other_group_subset", "below_window",
+          "above_window", "cut_inside_stripes", "bounds_on_events",
+          "cut_and_budget",
+          "empty_window", "before_history", "missing_user", "pinned_retained",
+          "pinned_budget")
+
+
+@pytest.mark.parametrize("case", _CASES)
+@pytest.mark.parametrize("where", ["monolith", "store_node", "sharded"])
+def test_columnwise_scan_matches_per_stripe_reference(windows_stores, where,
+                                                      case):
+    stores, truth, as_of = windows_stores
+    req = _window_cases(truth, as_of)[case]
+    if where == "monolith":
+        store = node = stores["monolith"]
+    else:
+        store = stores["sharded"]
+        node = store._node_for(req.user_id, req.generation)
+        if where == "store_node":
+            store = node
+    want = _reference_scan(node, req)
+    _assert_same_columns(store.scan(req), want)
+    _assert_same_columns(store.multi_range_scan([req, req])[1], want)
+    if case == "pinned_retained":   # the scrub changed the live window
+        live = store.scan(ScanRequest(0, "core", 0, as_of))
+        assert ev.batch_len(live) < ev.batch_len(want)
+    if case in ("below_window", "cut_and_budget"):
+        assert 0 < ev.batch_len(want) == req.max_events
+    empty = case in ("empty_window", "before_history", "missing_user")
+    assert (ev.batch_len(want) == 0) == empty
+
+
+def test_kept_header_equals_parsed_header():
+    """Compaction hands each stripe the header it encoded; a stripe made from
+    a blob alone (a bulk load of stored stripes) parses it once. Both equal
+    ``_read_header`` of the blob, and a decode from either reads the same
+    columns as a decode that parses the blob."""
+    from repro.storage.immutable_store import Stripe
+
+    gen = _gen(users=2)
+    as_of = 3 * ev.MS_PER_DAY
+    store, _ = _build_store(gen, 2, as_of)
+    made = [s for shard in store._shards for _, stripes in shard.values()
+            for s in stripes]
+    assert made
+    loaded = [Stripe(s.start_ts, s.end_ts, s.n_events, s.blob) for s in made]
+    reloaded = ImmutableUIHStore(SCHEMA, n_shards=4)
+    reloaded.bulk_load({(0, "core"): loaded[:3]}, generation=0)
+    for s in made + loaded:
+        header, body_off = columnar._read_header(s.blob)
+        assert s.layout.header == header
+        assert s.layout.body_off == body_off
+        traits = tuple(c["name"] for c in header["cols"])
+        want = columnar.decode_stripe(s.blob, SCHEMA)
+        got = dict(zip(traits, s.layout.decode(s.blob, traits)))
+        _assert_same_columns(got, want)
+        assert s.layout.decoded_bytes(traits[:2]) == sum(
+            c["len"] for c in header["cols"][:2])
+    assert loaded[0] == made[0]   # the layout is not part of a stripe's identity
