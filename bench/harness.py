@@ -6,6 +6,18 @@ by name: ``BENCHMARK.json`` names the cell's configuration file
 traffic file is ``bench/traffic/<mix>.json``, each metric is read by
 ``bench/metrics/<metric>.py`` and the limits of the check are in
 ``bench/limits/<workload>.json``.
+
+A configuration's module may also declare, for its per-layer metrics:
+``SCOPES``, the model scopes (``jax.named_scope`` names in the program)
+that it adds to ``bench.spans.DEFAULT_SCOPES``, each a key of the traced
+run's ``device_by_scope``; ``KERNELS``, ``{scope: cost(batch, config) ->
+(flops, bytes)}``, the work of the kernel the program runs under that scope
+for one prepared batch (every byte of its operands and results), which
+``bench/roofline.py`` sets against the scope's device time; and
+``counters(batch, config) -> {name: count}``. In a traced run, costs and
+counts are summed over the window's batches and reach the metrics as
+``w.kernel_cost`` and ``w.counters``. A traced run fails where the step it
+compiles holds none of the scopes, or lacks one of ``SCOPES``.
 """
 from __future__ import annotations
 
@@ -22,11 +34,11 @@ import tempfile
 import time
 from pathlib import Path
 from types import SimpleNamespace
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from bench import gen, reference, trace as trace_mod
+from bench import gen, reference, spans, trace as trace_mod
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -100,15 +112,47 @@ class CompileClock:
                 self.events += 1
 
 
+def model_scopes(model) -> Tuple[str, ...]:
+    """The scopes a traced run splits device time by: the defaults and the
+    configuration's ``SCOPES``, which hold every scope of its ``KERNELS``."""
+    scopes = spans.DEFAULT_SCOPES + tuple(getattr(model, "SCOPES", ()))
+    stray = set(getattr(model, "KERNELS", {})) - set(scopes)
+    if stray:
+        raise ValueError(f"KERNELS names scopes that are not in SCOPES: "
+                         f"{sorted(stray)}")
+    return scopes
+
+
+def step_scopes(hlo_text: str, model) -> Dict[str, Dict[str, str]]:
+    """``spans.op_scopes`` of the compiled step; raises where the step holds
+    none of the scopes, or lacks one that the configuration's ``SCOPES``
+    declares, since its device time would then read as unscoped."""
+    scopes = spans.op_scopes(hlo_text, model_scopes(model))
+    found = {sc for ops in scopes.values() for sc in ops.values()}
+    missing = set(getattr(model, "SCOPES", ())) - found
+    if not found or missing:
+        lack = sorted(missing) if found else "any of the scopes"
+        raise RuntimeError(f"the compiled step lacks {lack}: "
+                           "harness.compiled_step no longer matches the "
+                           "program's step")
+    return scopes
+
+
 class PrepRecorder:
     """The feed's ``prep_fn``: runs the configuration's prep and records,
     per batch in the order the trainer receives them, the rows' example
-    keys and the batch's useful FLOPs."""
+    keys and the batch's useful FLOPs; with ``per_layer``, also each of the
+    configuration's ``KERNELS`` costs and its ``counters``."""
 
-    def __init__(self, model, config: dict, seed: int):
+    def __init__(self, model, config: dict, seed: int,
+                 per_layer: bool = False):
         self.model, self.config, self.seed = model, config, seed
         self.keys: List[List[tuple]] = []
         self.flops: List[float] = []
+        self.kernels = getattr(model, "KERNELS", {}) if per_layer else {}
+        self.count = getattr(model, "counters", None) if per_layer else None
+        self.kernel_cost: List[Dict[str, Tuple[float, float]]] = []
+        self.counters: List[Dict[str, float]] = []
 
     def __call__(self, raw: dict) -> dict:
         out = self.model.prep(raw, self.config, len(self.keys), self.seed)
@@ -117,7 +161,29 @@ class PrepRecorder:
                                   raw["cand_item_id"].tolist())))
         self.flops.append(float(self.model.flops_per_row(out, self.config)
                                 .sum()))
+        if self.kernels:
+            self.kernel_cost.append({
+                scope: tuple(float(x) for x in cost(out, self.config))
+                for scope, cost in self.kernels.items()})
+        if self.count is not None:
+            self.counters.append({k: float(v) for k, v in
+                                  self.count(out, self.config).items()})
         return out
+
+    def sums(self, first: int, last: int
+             ) -> Tuple[Dict[str, Tuple[float, float]], Dict[str, float]]:
+        """Each kernel's ``(flops, bytes)`` and each counter, summed over
+        batches ``first`` to ``last`` (exclusive)."""
+        cost: Dict[str, Tuple[float, float]] = {}
+        for batch in self.kernel_cost[first:last]:
+            for scope, (f, b) in batch.items():
+                f0, b0 = cost.get(scope, (0.0, 0.0))
+                cost[scope] = (f0 + f, b0 + b)
+        counts: Dict[str, float] = {}
+        for batch in self.counters[first:last]:
+            for k, v in batch.items():
+                counts[k] = counts.get(k, 0.0) + v
+        return cost, counts
 
 
 class TimedFeed:
@@ -191,6 +257,30 @@ def _worker_busy(snap) -> float:
     return 0.0 if w is None else w.busy_time_s
 
 
+def compiled_step(trainer, batch):
+    """The trainer's step compiled for batches shaped like ``batch``, as
+    ``Trainer.step_hlo_text`` compiles it (from the persistent cache where
+    the run filled it), for its HLO text and its ``memory_analysis()``."""
+    import jax
+
+    def spec(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                    sharding=getattr(x, "sharding", None))
+
+    mbs = jax.eval_shape(trainer._microbatches, batch)
+    args = jax.tree.map(spec, (trainer.params, trainer.opt_state,
+                               trainer.ef_state))
+    return trainer._jit_step.lower(*args, mbs).compile()
+
+
+def compiled_bytes(compiled) -> int:
+    """Device bytes a compiled program needs: arguments, outputs and
+    temporaries, an output that aliases an argument counted once."""
+    m = compiled.memory_analysis()
+    return int(m.argument_size_in_bytes + m.output_size_in_bytes
+               - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
 def _peak_bytes(devices) -> int:
     return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                for d in devices)
@@ -207,6 +297,11 @@ def use_compile_cache() -> str:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     return path
+
+
+# the trace's readings a traced run's result line carries under "breakdown"
+BREAKDOWN = ("device_ops", "idle_gaps", "idle_split_s", "idle_by_span",
+             "idle_elsewhere", "device_by_scope")
 
 
 def run(cell: Cell, seed: int, seconds: float, trace: bool,
@@ -240,23 +335,25 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
            "count": len(devices), "memory_peak_bytes": w.peak_bytes}
     out = {"correct": w.correct, "attempted": w.steps,
            "failed": w.failed_steps, "metrics": values, "device": dev}
+    if trace:
+        dev["memory_compiled_bytes"] = w.compiled_bytes
     if trace and w.trace is not None:
         dev["busy_s"] = w.trace["busy_s"]
         dev["window_s"] = w.trace["window_s"]
-        out["breakdown"] = {"device_ops": w.trace["device_ops"],
-                            "idle_gaps": w.trace["idle_gaps"]}
+        out["breakdown"] = {k: w.trace[k] for k in BREAKDOWN}
     out["checks"] = w.checks
     return out
 
 
-def first_steps(cell: Cell, seed: int, min_window_s: float, say
-                ) -> SimpleNamespace:
+def first_steps(cell: Cell, seed: int, min_window_s: float, say,
+                per_layer: bool = False) -> SimpleNamespace:
     """Set-up up to and through the first three steps: the sim, the
     parameters, the ``Trainer`` over ``open_feed`` and the program's
     readings the check compares (the three losses, the first gradient from
     AdamW's first moment, as leaf norms and as leaves on the host, each
     leaf's change after step 3).
-    The feed is sized for a window of ``min_window_s`` seconds."""
+    The feed is sized for a window of ``min_window_s`` seconds; with
+    ``per_layer`` its batches' kernel costs and counters are recorded."""
     import jax
     import jax.numpy as jnp
 
@@ -290,7 +387,7 @@ def first_steps(cell: Cell, seed: int, min_window_s: float, say
                 + int(math.ceil(min_window_s * tr["rows_per_s_cap"])))
     spec = gen.dataset_spec(tr["feed"], c["projection"], c["seq_len"], seed,
                             min_rows)
-    rec = PrepRecorder(model, c, seed)
+    rec = PrepRecorder(model, c, seed, per_layer)
     feed = open_feed(spec, sim, prep_fn=rec)
     tf = TimedFeed(feed, int(tr["sample_batches"]), seed)
     try:
@@ -317,7 +414,7 @@ def _run_trainer(cell: Cell, seed: int, seconds: float, trace: bool,
                  say) -> SimpleNamespace:
     import jax
 
-    st = first_steps(cell, seed, seconds, say)
+    st = first_steps(cell, seed, seconds, say, per_layer=trace)
     trainer, feed, tf = st.trainer, st.feed, st.tf
     tdir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
     try:
@@ -349,15 +446,19 @@ def _run_trainer(cell: Cell, seed: int, seconds: float, trace: bool,
     window_s = float(ends[-1] - tf.t0)
     intervals = np.diff(np.concatenate([[tf.t0], ends]))
     window_losses = [h["loss"] for h in trainer.history[-steps:]]
-    summary = None
+    summary = n_bytes = None
     if trace:
+        step = compiled_step(trainer, next(iter(tf.kept.values())))
+        n_bytes = compiled_bytes(step)
         path = glob.glob(f"{tdir}/**/*.xplane.pb", recursive=True)
         reduced = trace_mod.from_xplane(path[0])
+        reduced["scopes"] = step_scopes(step.as_text(), cell.model)
+        del step
         if keep_trace:
             Path(keep_trace).write_text(json.dumps(
                 trace_mod.crop(reduced, 3)))
         shutil.rmtree(tdir, ignore_errors=True)
-        summary = trace_mod.summarize(reduced)
+        summary = spans.summarize(reduced)
     del trainer
     st.trainer = None
     gc.collect()            # the jitted step holds the trainer in a cycle
@@ -367,12 +468,14 @@ def _run_trainer(cell: Cell, seed: int, seconds: float, trace: bool,
     say(f"check: {time.perf_counter() - t:.1f}s; window {steps} steps in "
         f"{window_s:.2f}s")
     cb, ca = before.client, after.client
+    kernel_cost, counters = st.rec.sums(tf._first, tf.delivered)
     return SimpleNamespace(
         seconds=window_s, steps=steps, rows=steps * st.batch,
         intervals=intervals, setup_s=setup_s, compiles_in_window=in_window,
         flops=float(sum(st.rec.flops[tf._first:tf.delivered])),
         chips=cell.chips, device_kind=devices[0].device_kind,
-        peak_bytes=peak, trace=summary,
+        peak_bytes=peak, compiled_bytes=n_bytes, trace=summary,
+        kernel_cost=kernel_cost, counters=counters,
         starved_s=ca.starved_time_s - cb.starved_time_s,
         h2d_s=ca.h2d_time_s - cb.h2d_time_s,
         worker_busy_s=_worker_busy(after) - _worker_busy(before),

@@ -1,28 +1,26 @@
 """The program's spans and model scopes in a profiler trace: what the trainer
-thread was doing in each idle gap of the device, and which part of the model
-each device op belongs to.
+thread was doing in each idle gap of the device, how long each program span
+ran, and which part of the model each device op belongs to.
 
-``from_xplane`` keeps what ``bench.trace.from_xplane`` keeps plus the
-program's ``repro.*`` host events and, outside ``planes`` (so that
-``bench.trace.summarize`` reads the same trace as before), each device's XLA
-module intervals. A host line is one thread; every Python thread's line is
-named ``python``, so a thread is its line's place in its plane, never the
-line's name. ``op_scopes`` maps each instruction of a compiled program to the
-model scope in its ``metadata={op_name=...}`` (the TPU's op events carry no
-op name); the trace carries that map under ``scopes``. ``summarize`` reduces
-it all to new keys only; times are in nanoseconds on the profiler's clock.
+``bench.trace.from_xplane`` keeps the program's ``repro.*`` host events and
+each device's XLA module intervals (``modules``). ``op_scopes`` maps each
+instruction of a compiled program to the model scope in its
+``metadata={op_name=...}`` (the TPU's op events carry no op name); the trace
+carries that map under ``scopes``. ``summarize`` adds its keys to
+``bench.trace.summarize``'s; times are in nanoseconds on the profiler's
+clock.
 """
 from __future__ import annotations
 
 import bisect
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 from bench import trace
 
-SCOPES = ("embed", "encoder", "logits", "optimizer")
-PROGRAM = "repro."
-MODULE_LINE = "XLA Modules"
+# the model scopes (jax.named_scope) of the program's recsys models; a
+# configuration module adds its own as ``SCOPES``
+DEFAULT_SCOPES = ("embed", "encoder", "logits", "optimizer")
 # the trainer thread's spans that split the idle time, outermost first
 SPLIT = (("repro.train.dispatch", "dispatch"),
          ("repro.train.readback", "readback"),
@@ -34,21 +32,23 @@ _INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?op_name="([^"]*)"')
 _PART = re.compile(r"^(?:[\w.\-]+\()*([\w.\-]+)\)*$")
 
 
-def scope_of(op_name: str) -> str:
-    """The innermost model scope named in an op name, a scope wrapped by a
-    transformation (``transpose(jvp(logits))``) counted as that scope; ""
+def scope_of(op_name: str, scopes: Iterable[str]) -> str:
+    """The innermost of ``scopes`` named in an op name, a scope wrapped by
+    a transformation (``transpose(jvp(logits))``) counted as that scope; ""
     where there is none."""
     found = ""
     for part in op_name.split("/"):
         m = _PART.match(part)
-        if m and m.group(1) in SCOPES:
+        if m and m.group(1) in scopes:
             found = m.group(1)
     return found
 
 
-def op_scopes(hlo_text: str) -> Dict[str, Dict[str, str]]:
+def op_scopes(hlo_text: str, scopes: Iterable[str]
+              ) -> Dict[str, Dict[str, str]]:
     """``{module: {instruction: scope}}`` from compiled HLO text, for the
-    instructions under a scope."""
+    instructions under one of ``scopes``."""
+    scopes = frozenset(scopes)
     out: Dict[str, Dict[str, str]] = {}
     ops: Dict[str, str] = {}
     for line in hlo_text.splitlines():
@@ -57,52 +57,9 @@ def op_scopes(hlo_text: str) -> Dict[str, Dict[str, str]]:
             continue
         m = _INSTR.match(line)
         if m:
-            sc = scope_of(m.group(2))
+            sc = scope_of(m.group(2), scopes)
             if sc:
                 ops[m.group(1)] = sc
-    return out
-
-
-def _events(line) -> List[list]:
-    return [[e.name, int(e.start_ns), int(e.duration_ns)]
-            for e in line.events]
-
-
-def from_xplane(path: str) -> dict:
-    """``bench.trace.from_xplane``'s trace with the ``repro.*`` host events
-    kept too, and ``modules``: ``{device plane: [[module, start, duration],
-    ...]}``."""
-    from jax.profiler import ProfileData
-
-    planes, modules = [], {}
-    for plane in ProfileData.from_file(path).planes:
-        device = trace.DEVICE_PLANE.match(plane.name)
-        lines = []
-        for line in plane.lines:
-            if device and line.name == MODULE_LINE:
-                modules[plane.name] = _events(line)
-                continue
-            evs = (_events(line) if line.name == trace.OP_LINE else []) \
-                if device else [
-                    ev for ev in _events(line)
-                    if ev[0].startswith((trace.ANNOTATION, PROGRAM))]
-            if evs:
-                lines.append({"name": line.name, "events": evs})
-        if lines:
-            planes.append({"name": plane.name, "lines": lines})
-    return {"planes": planes, "modules": modules}
-
-
-def crop(t: dict, steps: int) -> dict:
-    """``bench.trace.crop`` keeping ``modules`` and ``scopes`` too."""
-    out = trace.crop(t, steps)
-    host = [ev for p in out["planes"]
-            if not trace.DEVICE_PLANE.match(p["name"])
-            for ln in p["lines"] for ev in ln["events"]]
-    t1 = max(s + d for n, s, d in host if n == trace.WINDOW)
-    out["modules"] = {k: [ev for ev in v if ev[1] < t1]
-                      for k, v in t.get("modules", {}).items()}
-    out["scopes"] = t.get("scopes", {})
     return out
 
 
@@ -157,7 +114,8 @@ def _split(path: Tuple[str, ...]) -> str:
 
 
 def summarize(t: dict, top: int = 10) -> Optional[dict]:
-    """Within the ``bench.window`` annotation: ``idle_split_s``, the
+    """``bench.trace.summarize``'s readings, and within the
+    ``bench.window`` annotation: ``idle_split_s``, the
     device's idle seconds by what the trainer thread (the line holding the
     window) was inside: ``dispatch``, ``readback``, ``feed`` (``feed.get``)
     or ``unspanned`` (none of the three), summing to the idle time;
@@ -166,9 +124,11 @@ def summarize(t: dict, top: int = 10) -> Optional[dict]:
     another thread was inside each ``repro.*`` span; ``device_by_scope``,
     device-busy seconds by model scope, each instant counted once by its
     innermost op (``unscoped`` where the op has no scope or the trace no
-    ``scopes``); ``idle_gaps``, the longest gaps named ``<bench annotation>
-    > <innermost trainer span>``. Per-chip seconds are averaged over the
-    chips. None where ``bench.trace.summarize`` reads nothing."""
+    ``scopes``); ``span_s``, each ``repro.*`` span's seconds, unioned on
+    each thread and summed over the threads; ``idle_gaps`` (in place of
+    ``bench.trace.summarize``'s), the longest gaps named ``<bench
+    annotation> > <innermost trainer span>``. Per-chip seconds are averaged
+    over the chips. None where ``bench.trace.summarize`` reads nothing."""
     base = trace.summarize(t, top)
     if base is None:
         return None
@@ -183,16 +143,24 @@ def summarize(t: dict, top: int = 10) -> Optional[dict]:
                           for ev in ln["events"]))
 
     def program(ln) -> List[list]:
-        return [ev for ev in ln["events"] if ev[0].startswith(PROGRAM)]
+        return [ev for ev in ln["events"] if ev[0].startswith(trace.PROGRAM)]
 
     own = [(s, e, path) for s, e, path in _layers(next(
         program(ln) for pi, li, ln in host if (pi, li) == trainer))
            if trace._clip(s, e, w0, w1)]
     others: Dict[str, List[Tuple[int, int]]] = {}
+    span_ns: Dict[str, int] = {}
     for pi, li, ln in host:
-        if (pi, li) != trainer:
-            for name, s, d in program(ln):
+        inside: Dict[str, List[Tuple[int, int]]] = {}
+        for name, s, d in program(ln):
+            if (pi, li) != trainer:
                 others.setdefault(name, []).append((s, s + d))
+            c = trace._clip(s, s + d, w0, w1)
+            if c:
+                inside.setdefault(name, []).append(c)
+        for name, iv in inside.items():
+            span_ns[name] = span_ns.get(name, 0) + sum(
+                e - s for s, e in trace._union(iv))
     others = {k: trace._union(v) for k, v in others.items()}
     annotated = [(ev[0], ev[1], ev[1] + ev[2])
                  for _, _, ln in host for ev in ln["events"]
@@ -242,7 +210,7 @@ def summarize(t: dict, top: int = 10) -> Optional[dict]:
                 named.append((e - s, s, e, max(inner, key=inner.get)))
     n = len(chips)
 
-    def sec(d: Dict[str, int]) -> Dict[str, float]:
+    def sec(d: Dict[str, int], n: int = n) -> Dict[str, float]:
         return {k: v / n / 1e9 for k, v in
                 sorted(d.items(), key=lambda kv: kv[1], reverse=True)}
 
@@ -256,10 +224,12 @@ def summarize(t: dict, top: int = 10) -> Optional[dict]:
 
     named.sort(reverse=True)
     return {
+        **base,
         "idle_split_s": {k: v / n / 1e9 for k, v in split.items()},
         "idle_by_span": sec(by_span),
         "idle_elsewhere": sec(elsewhere),
         "device_by_scope": sec(by_scope),
+        "span_s": sec(span_ns, 1),
         "idle_gaps": [[f"{what(s, e)} > {inner}", d / 1e9]
                       for d, s, e, inner in named[:top]],
     }

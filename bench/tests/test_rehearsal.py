@@ -43,6 +43,33 @@ def test_sound_run_is_correct(workload):
     assert list(out)[-1] == "checks"
 
 
+def test_traced_run_past_the_chip_check():
+    """The traced path on the CPU: the step's compiled bytes reach the
+    device entry; with no TPU plane the trace reads nothing, so the
+    per-layer metrics read from it are left out."""
+    cell = tiny_cell(WORKLOADS[0])
+    # step_mfu needs a chip's peak
+    cell.metrics = [m for m in cell.metrics if m["name"] != "step_mfu"]
+    out = harness.run(cell, 4_000_000_321, 1.0, True, time.perf_counter(),
+                      require_chip=False, say=lambda s: None)
+    assert out["correct"], out["checks"]
+    assert out["device"]["memory_compiled_bytes"] > 0
+    assert set(out["metrics"]) == {"dpp_busy_us_per_row", "feed_wait_share",
+                                   "h2d_ms_per_step"}
+    assert "breakdown" not in out
+
+
+def test_compiled_step_is_the_programs():
+    cell = tiny_cell(WORKLOADS[0])
+    st = harness.first_steps(cell, 4_000_000_333, 0.0, lambda s: None)
+    try:
+        batch = next(iter(st.tf.kept.values()))
+        assert (harness.compiled_step(st.trainer, batch).as_text()
+                == st.trainer.step_hlo_text(batch))
+    finally:
+        st.feed.close(timeout=0.5)
+
+
 def _stuck_step(monkeypatch):
     """A step that returns its state unchanged."""
     from repro.train.train_loop import Trainer

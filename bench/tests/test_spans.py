@@ -82,6 +82,25 @@ def test_device_by_scope_counts_each_instant_once():
     assert _ns(spans.summarize(t)["device_by_scope"]) == {"unscoped": 100}
 
 
+def test_span_seconds_union_per_thread_sum_over_threads():
+    t = _trace()
+    # a second feed.get on the trainer line, overlapping the first: one
+    # thread's time under a name is counted once
+    t["planes"][1]["lines"][1]["events"].append(["repro.feed.get", 104, 6])
+    assert _ns(spans.summarize(t)["span_s"]) == {
+        "repro.train.step": 102, "repro.dpp.scan": 90,
+        "repro.train.dispatch": 58, "repro.feed.get": 50,
+        "repro.train.readback": 35, "repro.dpp.featurize": 30,
+        "repro.host.gc": 5, "repro.train.inputs": 4}
+
+
+def test_trace_readings_are_kept_beside_the_spans():
+    t = _trace()
+    base, s = trace.summarize(t), spans.summarize(t)
+    assert {k: v for k, v in s.items() if k in base and k != "idle_gaps"} \
+        == {k: v for k, v in base.items() if k != "idle_gaps"}
+
+
 def test_gaps_named_by_annotation_and_innermost_span():
     gaps = [(n, round(d * 1e9)) for n, d in spans.summarize(_trace())
             ["idle_gaps"]]
@@ -109,16 +128,21 @@ def test_scopes_from_hlo_text():
         "HloModule jit_other, entry_computation_layout={()->()}",
         '  %fusion.7 = f32[8]{0} fusion(%p), metadata={op_name='
         '"jit(other)/optimizer/sub"}'])
-    assert spans.op_scopes(text) == {
+    assert spans.op_scopes(text, spans.DEFAULT_SCOPES) == {
         "jit__train_step": {"fusion.7": "logits", "while.2": "encoder"},
         "jit_other": {"fusion.7": "optimizer"}}
-    assert spans.scope_of("jit(s)/encoder/while/body/embed/gather") == "embed"
-    assert spans.scope_of("jit(s)/transpose(jvp(encoderx))/dot") == ""
+    assert spans.scope_of("jit(s)/encoder/while/body/embed/gather",
+                          spans.DEFAULT_SCOPES) == "embed"
+    assert spans.scope_of("jit(s)/transpose(jvp(encoderx))/dot",
+                          spans.DEFAULT_SCOPES) == ""
+    # a scope the configuration adds is found inside a default one
+    assert spans.scope_of("jit(s)/encoder/jvp(attn)/dot",
+                          spans.DEFAULT_SCOPES + ("attn",)) == "attn"
 
 
 def test_recorded_trace():
     """The first three window steps of ``bert4rec.short_seq`` traced on
-    one TPU v5e (``spans_run.py --keep-trace``)."""
+    one TPU v5e (``run.py --trace 1 --keep-trace``)."""
     t = json.loads(gzip.decompress(FIXTURE.read_bytes()))
     base, s = trace.summarize(t), spans.summarize(t)
     assert base["steps"] == 3

@@ -9,7 +9,12 @@ import pytest
 
 from bench import trace
 
-FIXTURE = Path(__file__).parent / "fixtures" / "bert4rec_trace.json.gz"
+FIXTURES = Path(__file__).parent / "fixtures"
+FIXTURE = FIXTURES / "bert4rec_trace.json.gz"
+# trace.summarize's readings of both recorded traces as they were read when
+# the trace kept the bench.* host events alone (of the second, which holds
+# the program's spans too, those events only)
+READINGS = json.loads((FIXTURES / "trace_readings.json").read_text())
 
 
 def _trace(ops, host):
@@ -39,6 +44,41 @@ def test_hand_made_trace():
     assert gaps == [("bench.train_step", 30), ("bench.train_step", 10),
                     ("bench.feed_fetch", 10)]
     assert s["device_ops"][0][0] in {"fusion.1", "fusion.2"}
+
+
+def test_program_spans_neither_count_steps_nor_name_gaps():
+    ops = [["fusion.1", 110, 20]]
+    host = [["bench.window", 100, 100], ["bench.train_step", 105, 20],
+            ["repro.train.step", 100, 100], ["repro.feed.get", 130, 70]]
+    s = trace.summarize(_trace(ops, host))
+    assert s["steps"] == 1
+    assert [name for name, _ in s["idle_gaps"]] == ["no annotation",
+                                                    "bench.train_step"]
+
+
+@pytest.mark.parametrize("name", sorted(READINGS))
+def test_recorded_readings_unchanged(name):
+    t = json.loads(gzip.decompress((FIXTURES / name).read_bytes()))
+    s = trace.summarize(t)
+    assert {k: s[k] for k in READINGS[name]} == READINGS[name]
+
+
+def test_from_xplane_keeps_bench_and_program_events(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("bench.window"):
+        with jax.profiler.TraceAnnotation("repro.train.step"):
+            with jax.profiler.TraceAnnotation("elsewhere.step"):
+                jnp.ones(8).sum().block_until_ready()
+    jax.profiler.stop_trace()
+    path, = tmp_path.glob("**/*.xplane.pb")
+    t = trace.from_xplane(str(path))
+    names = {ev[0] for p in t["planes"] for ln in p["lines"]
+             for ev in ln["events"]}
+    assert names == {"bench.window", "repro.train.step"}
+    assert t["modules"] == {}           # no TPU plane here
 
 
 def test_no_window_or_no_device_op_reads_nothing():

@@ -1,11 +1,13 @@
 """From a profiler trace to the device numbers of one measured window.
 
-``from_xplane`` keeps what the reduction needs from the ``.xplane.pb`` that
-``jax.profiler`` writes: each TPU device plane's op line and the host
-events the benchmark annotates (``bench.*``). ``summarize`` reduces that to
-busy time, top device ops, collective time and the longest idle gaps, each
-gap named by the benchmark annotation that overlaps it most. Times are in
-nanoseconds on the profiler's clock.
+``from_xplane`` keeps what the reductions need from the ``.xplane.pb`` that
+``jax.profiler`` writes: each TPU device plane's op line, the host events
+the benchmark annotates (``bench.*``) and those the program records
+(``repro.*``, read by ``bench/spans.py``), and, outside ``planes``, each
+device's XLA module intervals. ``summarize`` reduces that to busy time, top
+device ops, collective time and the longest idle gaps, each gap named by the
+benchmark annotation that overlaps it most; it reads the ``bench.*``
+annotations only. Times are in nanoseconds on the profiler's clock.
 """
 from __future__ import annotations
 
@@ -14,35 +16,45 @@ from typing import Dict, List, Optional, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OP_LINE = "XLA Ops"
+MODULE_LINE = "XLA Modules"
 WINDOW = "bench.window"
 ANNOTATION = "bench."
+PROGRAM = "repro."
 COLLECTIVE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute"
     r"|allreduce|allgather|reducescatter|alltoall|psum", re.I)
 
 
+def _events(line) -> List[list]:
+    return [[e.name, int(e.start_ns), int(e.duration_ns)]
+            for e in line.events]
+
+
 def from_xplane(path: str) -> dict:
     """``{"planes": [{"name", "lines": [{"name", "events": [[name, start,
-    duration], ...]}]}]}`` with the device op lines and the host
-    ``bench.*`` events only."""
+    duration], ...]}]}], "modules": {device plane: [[module, start,
+    duration], ...]}}`` with the device op lines and the host ``bench.*``
+    and ``repro.*`` events only. A host line is one thread; every Python
+    thread's line is named ``python``, so a thread is its line's place in
+    its plane, never the line's name."""
     from jax.profiler import ProfileData
 
-    out = []
+    planes, modules = [], {}
     for plane in ProfileData.from_file(path).planes:
+        device = DEVICE_PLANE.match(plane.name)
         lines = []
         for line in plane.lines:
-            if DEVICE_PLANE.match(plane.name):
-                keep = line.name == OP_LINE
-                evs = [[e.name, int(e.start_ns), int(e.duration_ns)]
-                       for e in line.events] if keep else []
-            else:
-                evs = [[e.name, int(e.start_ns), int(e.duration_ns)]
-                       for e in line.events if e.name.startswith(ANNOTATION)]
+            if device and line.name == MODULE_LINE:
+                modules[plane.name] = _events(line)
+                continue
+            evs = (_events(line) if line.name == OP_LINE else []) \
+                if device else [ev for ev in _events(line)
+                                if ev[0].startswith((ANNOTATION, PROGRAM))]
             if evs:
                 lines.append({"name": line.name, "events": evs})
         if lines:
-            out.append({"name": plane.name, "lines": lines})
-    return {"planes": out}
+            planes.append({"name": plane.name, "lines": lines})
+    return {"planes": planes, "modules": modules}
 
 
 def short_name(name: str) -> str:
@@ -57,8 +69,8 @@ def short_name(name: str) -> str:
 
 
 def crop(trace: dict, steps: int) -> dict:
-    """The trace up to the end of the window's first ``steps`` steps (a
-    small recorded trace for tests)."""
+    """The trace up to the end of the window's first ``steps`` steps, its
+    ``modules`` and ``scopes`` with it (a small recorded trace for tests)."""
     host = [ev for p in trace["planes"] if not DEVICE_PLANE.match(p["name"])
             for ln in p["lines"] for ev in ln["events"]]
     w0 = max((ev for ev in host if ev[0] == WINDOW), key=lambda e: e[2])[1]
@@ -77,7 +89,10 @@ def crop(trace: dict, steps: int) -> dict:
             if evs:
                 lines.append({"name": ln["name"], "events": evs})
         out.append({"name": p["name"], "lines": lines})
-    return {"planes": out}
+    return {"planes": out,
+            "modules": {k: [ev for ev in v if ev[1] < t1]
+                        for k, v in trace.get("modules", {}).items()},
+            "scopes": trace.get("scopes", {})}
 
 
 def _union(iv: List[Tuple[int, int]]) -> List[Tuple[int, int]]:
@@ -106,7 +121,8 @@ def summarize(trace: dict, top: int = 10) -> Optional[dict]:
         return None
     _, w0, wd = max(windows, key=lambda ev: ev[2])
     w1 = w0 + wd
-    spans = [(ev[0], s, e) for ev in host if ev[0] != WINDOW
+    spans = [(ev[0], s, e) for ev in host
+             if ev[0].startswith(ANNOTATION) and ev[0] != WINDOW
              for s, e in [(ev[1], ev[1] + ev[2])] if _clip(s, e, w0, w1)]
     steps = sum(1 for name, s, e in spans
                 if name == "bench.train_step" and w0 <= e <= w1)
