@@ -17,11 +17,13 @@ _MODULES = {
     "dcn-v2": "repro.configs.dcn_v2",
     "dien": "repro.configs.dien",
     "bert4rec": "repro.configs.bert4rec",
+    "hstu": "repro.configs.hstu",
     "dlrm-uih": "repro.configs.dlrm_uih",
 }
 
-# the 10 assigned archs (dlrm-uih is the paper's own, listed separately)
-ASSIGNED: List[str] = [a for a in _MODULES if a != "dlrm-uih"]
+# the 10 assigned archs (dlrm-uih is the paper's own and hstu the generative
+# recommender the ultra-long histories feed, both listed separately)
+ASSIGNED: List[str] = [a for a in _MODULES if a not in ("dlrm-uih", "hstu")]
 
 
 def get_arch(arch_id: str) -> ArchSpec:

@@ -154,7 +154,7 @@ def calibrate_cell(arch_id: str, shape_name: str, mesh_name: str) -> dict:
         m2 = _measure(build_cell(spec, shape_name, mesh, cfg_override=mk(3)), mesh)
         return _extrapolate(m1, m2, full.n_layers, anchors=(2, 3))
     # recsys: unroll everything (models are shallow) -> exact
-    if arch_id in ("dien", "bert4rec", "dlrm-uih"):
+    if arch_id in ("dien", "bert4rec", "dlrm-uih", "hstu"):
         cfg = dc.replace(full, unroll_scans=True)
         return _measure(build_cell(spec, shape_name, mesh, cfg_override=cfg), mesh)
     # two-tower / dcn-v2 have no scans: production lowering is already exact

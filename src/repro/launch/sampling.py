@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.launch.steps import Cell
 from repro.models import gnn as G
+from repro.models import hstu as H
 from repro.models import recsys as R
 from repro.models import transformer as T
 from repro.train.optimizer import adamw_init
@@ -87,6 +88,7 @@ def sample_args(cell: Cell, family: str, seed: int = 0):
                     "dien": R.init_dien,
                     "bert4rec": R.init_bert4rec,
                     "dlrm-uih": R.init_dlrm_uih,
+                    "hstu": H.init_hstu,
                 }[cell.arch_id]
                 out.append(_cast_like(init_fn(key, cfg), arg))
             continue

@@ -17,6 +17,7 @@ from repro.configs.base import ArchSpec
 from repro.launch import shardings as SH
 from repro.launch.mesh import all_axes_of, data_axes_of
 from repro.models import gnn as G
+from repro.models import hstu as H
 from repro.models import recsys as R
 from repro.models import transformer as T
 from repro.train.optimizer import AdamWConfig, AdamWState, adamw_init, make_train_step
@@ -286,6 +287,20 @@ def _recsys_batch(arch_id: str, cfg, b: int, mesh, with_label: bool):
         else:
             spec["cand_item_id"] = S((b,), jnp.int32)
             sh["cand_item_id"] = bp()
+    elif arch_id == "hstu":
+        spec = {
+            "uih_item_id": S((b, cfg.seq_len), jnp.int32),
+            "uih_timestamp": S((b, cfg.seq_len), jnp.int32),
+            "uih_mask": S((b, cfg.seq_len), jnp.bool_),
+        }
+        sh = {"uih_item_id": bp(None), "uih_timestamp": bp(None),
+              "uih_mask": bp(None)}
+        if with_label:
+            spec["neg_ids"] = S((cfg.num_negatives,), jnp.int32)
+            sh["neg_ids"] = P(None)
+        else:
+            spec["cand_item_id"] = S((b,), jnp.int32)
+            sh["cand_item_id"] = bp()
     elif arch_id == "dlrm-uih":
         spec = {
             "uih_item_id": S((b, cfg.seq_len), jnp.int32),
@@ -302,7 +317,8 @@ def _recsys_batch(arch_id: str, cfg, b: int, mesh, with_label: bool):
         }
     else:
         raise KeyError(arch_id)
-    if with_label and arch_id not in ("two-tower-retrieval", "bert4rec"):
+    if with_label and arch_id not in ("two-tower-retrieval", "bert4rec",
+                                      "hstu"):
         spec["label"] = S((b,), jnp.float32)
         sh["label"] = bp()
     return spec, sh
@@ -318,6 +334,8 @@ _RECSYS_FNS = {
                  R.bert4rec_score_candidates),
     "dlrm-uih": (R.init_dlrm_uih, R.dlrm_uih_loss, R.dlrm_uih_forward,
                  R.dlrm_uih_score_candidates),
+    "hstu": (H.init_hstu, H.hstu_loss, H.hstu_forward,
+             H.hstu_score_candidates),
 }
 
 
@@ -354,7 +372,17 @@ def _recsys_flops(arch_id: str, cfg, b: int) -> float:
         d = cfg.d_seq
         per_tok = 12 * d * d + 2 * cfg.seq_len * d
         return 2.0 * b * cfg.seq_len * cfg.n_seq_layers * per_tok
+    if arch_id == "hstu":
+        return 2.0 * b * cfg.seq_len * cfg.n_blocks * _hstu_per_tok(cfg) \
+            + 2.0 * b * cfg.seq_len * cfg.num_negatives * cfg.embed_dim
     raise KeyError(arch_id)
+
+
+def _hstu_per_tok(cfg) -> float:
+    """Multiply-adds of one position in one HSTU block, attention over
+    half of the positions (causal)."""
+    d, hq, hv = cfg.embed_dim, cfg.n_heads * cfg.dqk, cfg.n_heads * cfg.dv
+    return d * (2 * hq + 2 * hv) + hv * d + cfg.seq_len / 2 * (hq + hv)
 
 
 def build_recsys_cell(spec: ArchSpec, shape_name: str, mesh,
@@ -510,6 +538,9 @@ def _retrieval_flops(arch_id: str, cfg, n: int) -> float:
                     + 2.0 * ((pairs + cfg.embed_dim) * cfg.top_mlp[0]
                              + cfg.top_mlp[0] * cfg.top_mlp[1]))
         return enc_once + n * per_cand
+    if arch_id == "hstu":
+        enc_once = 2.0 * cfg.seq_len * cfg.n_blocks * _hstu_per_tok(cfg)
+        return enc_once + 2.0 * n * cfg.embed_dim
     raise KeyError(arch_id)
 
 

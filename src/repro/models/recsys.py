@@ -480,28 +480,48 @@ def _bert4rec_nll(params, h, ids, mask_pos, neg_ids, cfg: BERT4RecConfig):
         nll = (logz - gold) * mask_pos
         return jnp.sum(nll) / jnp.maximum(jnp.sum(mask_pos), 1)
 
-    neg_emb = table[neg_ids]                                  # (N, D) small
     gold_emb = _seq_lookup(params["item_table"], ids, cfg, h.dtype)  # (B,S,D)
-    gold_logit = jnp.sum(h * gold_emb, axis=-1).astype(jnp.float32)  # (B, S)
-    b, s, d = h.shape
-    lc = cfg.loss_chunk if cfg.loss_chunk and s % cfg.loss_chunk == 0 else s
-    n_chunks = s // lc
-    hs = h.reshape(b, n_chunks, lc, d).transpose(1, 0, 2, 3)
-    gl = gold_logit.reshape(b, n_chunks, lc).transpose(1, 0, 2)
-    mp = mask_pos.reshape(b, n_chunks, lc).transpose(1, 0, 2)
+    return sampled_softmax_nll(h, gold_emb, table[neg_ids], mask_pos,
+                               chunk=cfg.loss_chunk, unroll=cfg.unroll_scans)
 
-    def chunk(carry, inp):
-        hi, gi, mi = inp
-        neg_logits = jnp.einsum("bsd,nd->bsn", hi, neg_emb).astype(jnp.float32)
+
+def sampled_softmax_nll(h, gold_emb, neg_emb, weights, *,
+                        temperature: float = 1.0, gold_ids=None,
+                        neg_ids=None, chunk: int = 0,
+                        unroll: bool = False) -> jax.Array:
+    """Mean over the positions ``weights`` selects of the NLL of a softmax
+    over [gold | negatives shared by the batch].
+
+    h, gold_emb: (B, S, D); neg_emb: (N, D); weights: (B, S). Logits are
+    dot products over ``temperature``. With ``gold_ids`` (B, S) and
+    ``neg_ids`` (N,), a negative equal to the position's gold item is left
+    out. ``chunk`` scores the sequence that many positions at a time."""
+    gold_logit = jnp.sum(h * gold_emb, axis=-1).astype(jnp.float32) \
+        / temperature                                          # (B, S)
+    b, s, d = h.shape
+    lc = chunk if chunk and s % chunk == 0 else s
+    n_chunks = s // lc
+    split = lambda x: x.reshape(b, n_chunks, lc, *x.shape[2:]).swapaxes(0, 1)
+    collide = gold_ids is not None and neg_ids is not None
+    gi_ids = split(gold_ids) if collide else jnp.zeros((n_chunks,), jnp.int32)
+
+    def one(carry, inp):
+        hi, gi, mi, ids_i = inp
+        neg_logits = jnp.einsum("bsd,nd->bsn", hi, neg_emb).astype(
+            jnp.float32) / temperature
+        if collide:
+            neg_logits = jnp.where(ids_i[..., None] == neg_ids, -1e30,
+                                   neg_logits)
         # sampled softmax over [gold | negatives]; max per (b, s) position
         m = jnp.maximum(jnp.max(neg_logits, -1), gi)
         z = jnp.exp(gi - m) + jnp.sum(jnp.exp(neg_logits - m[..., None]), -1)
         nll = (m + jnp.log(z) - gi) * mi
         return carry + jnp.sum(nll), None
 
-    total, _ = jax.lax.scan(chunk, jnp.zeros((), jnp.float32), (hs, gl, mp),
-                            unroll=cfg.unroll_scans)
-    return total / jnp.maximum(jnp.sum(mask_pos), 1)
+    total, _ = jax.lax.scan(one, jnp.zeros((), jnp.float32),
+                            (split(h), split(gold_logit), split(weights),
+                             gi_ids), unroll=unroll)
+    return total / jnp.maximum(jnp.sum(weights), 1)
 
 
 def bert4rec_forward(params, batch, cfg: BERT4RecConfig) -> jax.Array:

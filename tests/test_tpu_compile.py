@@ -147,3 +147,64 @@ def test_sharded_init_draws_only_each_chips_rows(v5e_2x2, under_set_mesh):
     row_slice = FULL.item_vocab // 4 * FULL.embed_dim * 4
     assert mem.output_size_in_bytes >= row_slice
     assert mem.temp_size_in_bytes < row_slice // 8
+
+
+HSTU_B, HSTU_L, HSTU_HD, HSTU_T = 32, 4096, 256, 256
+
+
+def _hstu_args(one_chip, backward):
+    from repro.kernels.hstu_attention.hstu_attention import TABLE_LANES
+
+    bf = jnp.bfloat16
+    act = _spec(one_chip, (HSTU_B, HSTU_L, HSTU_HD), bf)
+    args = [_spec(one_chip, (HSTU_B,), jnp.int32), act, act, act]
+    if backward:
+        args.append(act)                               # output gradient
+    return args + [
+        _spec(one_chip, (HSTU_B, HSTU_L, 1), jnp.int32),
+        _spec(one_chip, (HSTU_B, 1, HSTU_L), jnp.int32),
+        _spec(one_chip, (HSTU_L // HSTU_T, HSTU_T, HSTU_T), jnp.float32),
+        _spec(one_chip, (1, TABLE_LANES), jnp.float32)]
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+def test_hstu_attention_compiles(one_chip, backward):
+    """HSTU's attention at the cell's shapes: 32 rows of 4,096 positions,
+    2 heads of 128, bfloat16, blocks of 256."""
+    from repro.kernels.hstu_attention import hstu_attention as k
+
+    fn = k.hstu_attention_bwd if backward else k.hstu_attention_fwd
+    c = fn.lower(*_hstu_args(one_chip, backward), block=HSTU_T, heads=2,
+                 n_buckets=129).compile()
+    _assert_mosaic(c)
+
+
+def test_hstu_train_step_fits_one_chip(one_chip, monkeypatch):
+    """The trainer's whole HSTU-large step at B=32, L=4,096 compiles with
+    both attention kernels in Mosaic, and its arguments, outputs and
+    temporaries fit one chip's 16 GB."""
+    from repro.configs.hstu import FULL
+    from repro.kernels import runtime
+    from repro.models.hstu import hstu_loss, init_hstu
+    from repro.train.optimizer import AdamWConfig, adamw_init
+    from repro.train.train_loop import Trainer, TrainerConfig
+
+    monkeypatch.setattr(runtime, "interpret_default", lambda: False)
+    shapes = jax.eval_shape(lambda: init_hstu(jax.random.PRNGKey(0), FULL))
+    tiny = jax.tree.map(lambda x: jnp.zeros((1,) * x.ndim, x.dtype), shapes)
+    trainer = Trainer(lambda p, b: hstu_loss(p, b, FULL), tiny,
+                      TrainerConfig(opt=AdamWConfig()))
+    spec = lambda x: _spec(one_chip, x.shape, x.dtype)
+    params = jax.tree.map(spec, shapes)
+    opt = jax.tree.map(spec, jax.eval_shape(adamw_init, shapes))
+    rows = (1, HSTU_B, FULL.seq_len)
+    batch = {"uih_item_id": _spec(one_chip, rows, jnp.int32),
+             "uih_timestamp": _spec(one_chip, rows, jnp.int32),
+             "uih_mask": _spec(one_chip, rows, jnp.bool_),
+             "neg_ids": _spec(one_chip, (1, FULL.num_negatives), jnp.int32)}
+    c = trainer._jit_step.lower(params, opt, None, batch).compile()
+    assert c.as_text().count('custom_call_target="tpu_custom_call"') == 2
+    m = c.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert total < 16e9
